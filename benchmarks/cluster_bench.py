@@ -234,7 +234,11 @@ def main() -> None:
     args = ap.parse_args()
 
     if args.backend == "jax":
-        os.environ.setdefault("REPRO_JAX_LEGACY_CPU", "1")
+        from pathlib import Path
+
+        from repro.compile_cache import use_compile_cache
+
+        use_compile_cache(Path(__file__).resolve().parents[1])
 
     names = [args.scenario] if args.scenario else sorted(SCENARIOS)
     entries, summary = [], {}

@@ -140,11 +140,9 @@ def main() -> None:
     ap.add_argument("--n-wl-ops", type=int, default=10_000)
     args = ap.parse_args()
 
-    # The perf contract: jax timings use XLA's legacy inline CPU runtime
-    # (process-global, so it must be exported before jax initializes --
-    # which is also why the loop side runs first, before any jax import).
-    os.environ.setdefault("REPRO_JAX_LEGACY_CPU", "1")
+    from pathlib import Path
 
+    from repro.compile_cache import use_compile_cache
     from repro.core.sim import SimConfig
     from repro.core.sim.sweep import sweep_latency
 
@@ -175,6 +173,7 @@ def main() -> None:
               f"({len(lats) * len(cands)} cells)", file=sys.stderr,
               flush=True)
 
+    use_compile_cache(Path(__file__).resolve().parents[1])
     for entry, (name, eng, dev, (nk, nw), lats, cands, n_ops) \
             in zip(entries, specs):
         cfg = SimConfig(P=12, seed=7, **dev)

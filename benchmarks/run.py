@@ -420,12 +420,11 @@ def main() -> None:
               f"{args.sweep_cache}", file=sys.stderr)
 
     if args.backend == "jax":
-        # Perf opt-in (see replay_jax._XLA_CPU_FLAGS): the CLI owns the
-        # process, so the legacy CPU runtime is safe here; jax has not
-        # initialized yet because replay_jax is imported lazily per sweep.
-        import os
+        from pathlib import Path
 
-        os.environ.setdefault("REPRO_JAX_LEGACY_CPU", "1")
+        from repro.compile_cache import use_compile_cache
+
+        use_compile_cache(Path(__file__).resolve().parents[1])
     backend_opts = {"use_pallas": args.backend_pallas,
                     "unroll": args.backend_unroll,
                     "substeps": args.backend_substeps,

@@ -155,7 +155,11 @@ def main() -> None:
     args = ap.parse_args()
 
     if args.backend == "jax":
-        os.environ.setdefault("REPRO_JAX_LEGACY_CPU", "1")
+        from pathlib import Path
+
+        from repro.compile_cache import use_compile_cache
+
+        use_compile_cache(Path(__file__).resolve().parents[1])
 
     suite = SMOKE if args.smoke else FULL
     res = run_suite(suite, args.backend)

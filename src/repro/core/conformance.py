@@ -45,13 +45,13 @@ from __future__ import annotations
 
 import argparse
 import math
-import os
 import random
 import sys
 from dataclasses import dataclass, replace
 from pathlib import Path
 from typing import Callable, Iterable, Sequence
 
+from ..compile_cache import use_compile_cache
 from .experiment import Experiment, RunArtifact, RunOptions, Scenario
 
 __all__ = [
@@ -727,8 +727,7 @@ def main(argv: Sequence[str] | None = None) -> int:
     s.add_argument("--out", default=None, metavar="FILE")
 
     args = ap.parse_args(argv)
-    # match benchmarks.run: keep the jax grid on the stable CPU path
-    os.environ.setdefault("REPRO_JAX_LEGACY_CPU", "1")
+    use_compile_cache(Path(__file__).resolve().parents[3])
 
     if args.cmd == "sample":
         sc = scenario_for_seed(args.seed)

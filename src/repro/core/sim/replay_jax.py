@@ -14,9 +14,9 @@ One scan step executes exactly one suboperation of one thread in every grid
 cell.  The step body itself lives in :mod:`repro.kernels.sched_step` (the
 fused whole-step scheduler kernel; see that module for the state layout):
 
-  * thread selection: ready threads carry a monotone FIFO *stamp* with
-    their thread id packed into the low mantissa bits, so a single ``min``
-    reduction pops the ring head -- no ``argmin`` anywhere in the step;
+  * thread selection: ready threads carry a monotone FIFO *stamp* (their
+    last pop time), so a lexicographic (stamp, tid) minimum pops the ring
+    head;
   * wake drain: every parked thread whose IO completed re-joins the back
     of the ring in wake order in one masked pass -- the *exact* drain the
     loop backends perform, not a bounded-per-step approximation -- and
@@ -54,17 +54,18 @@ routes mixture latencies through the loop backend per-cell.
 ``use_pallas=True`` runs the scan through the fused Pallas kernel
 (:func:`repro.kernels.sched_step.fused_steps`): the scheduler planes stay
 resident in VMEM across ``substeps`` inner steps per kernel invocation.
-On TPU that is the compiled fast path; on CPU it runs in interpreter mode,
-which is far too slow for real sweeps but lets CI validate the kernel
+It does not compile for TPU yet (its state is float64, which Mosaic does
+not lower; ROADMAP Queue 1 item 3), so :func:`sweep_grid` refuses it on
+any backend but CPU.  On CPU it runs in interpreter mode, which is far
+too slow for real sweeps but lets the tests validate the kernel
 bit-for-bit against the pure-jnp scan on tiny grids.
 
-Everything here is computed in float64 (``jax.experimental.enable_x64``):
-the state mixes ~second-scale clocks with 50 ns context switches, which
-float32 cannot carry.  Perf runs on CPU should additionally export
-``REPRO_JAX_LEGACY_CPU=1`` before jax initializes (the benchmark entry
-points do) -- XLA's legacy inline runtime executes this scan ~2-5x
-faster per step than the thunk runtime; see ``_XLA_CPU_FLAGS`` below for
-why it is opt-in rather than the default.
+Everything here is computed in float64 (``jax.enable_x64``): the state
+mixes ~second-scale clocks with 50 ns context switches, which float32
+cannot carry.  On TPU, XLA emulates float64 with float32's exponent range
+and ~48 mantissa bits; the program therefore reads no float's bits (no
+64-bit ``bitcast_convert_type``, which that emulation cannot rewrite) and
+keeps every constant inside float32's range.
 """
 from __future__ import annotations
 
@@ -78,29 +79,11 @@ from typing import Sequence
 
 import numpy as np
 
-# Opt-in fast path for perf runs: XLA's legacy inline CPU runtime
-# executes this module's scan body ~2-5x faster per op than the thunk
-# runtime that became the default in jax 0.4.32 (command-buffer dispatch
-# overhead on many small fused ops).  It is NOT enabled by default --
-# XLA flags are process-global, the legacy runtime flushes denormals
-# (FTZ/DAZ), and this library must not change numerics for every other
-# jax user in the process.  Perf entry points (benchmarks/jax_grid_bench
-# and ``benchmarks.run --backend jax``) export REPRO_JAX_LEGACY_CPU=1
-# before jax initializes its CPU client; the sim itself is runtime-
-# agnostic (its only sub-normal-magnitude values, the EPOCH ring
-# tickets, are deliberately normal floats).
-_XLA_CPU_FLAGS = "--xla_cpu_use_thunk_runtime=false"
-if os.environ.get("REPRO_JAX_LEGACY_CPU"):
-    _flags = os.environ.get("XLA_FLAGS", "")
-    if "xla_cpu_use_thunk_runtime" not in _flags:
-        os.environ["XLA_FLAGS"] = (_flags + " " + _XLA_CPU_FLAGS).strip()
-
-# Host-device sharding opt-in (same contract as REPRO_JAX_LEGACY_CPU:
-# process-global, so only entry points that own the process should set
-# it, *before* jax initializes).  XLA presents the host as N virtual CPU
-# devices; sweep_grid(host_devices=N) then shard_maps cohorts over them
-# so the jax backend uses every container core the way the forked loop
-# pipeline already does.
+# Host-device sharding opt-in (process-global, so only entry points that
+# own the process should set it, *before* jax initializes).  XLA presents
+# the host as N virtual CPU devices; sweep_grid(host_devices=N) then
+# shard_maps cohorts over them so the jax backend uses every host core
+# the way the forked loop pipeline already does (CPU backend only).
 _n_host = os.environ.get("REPRO_JAX_HOST_DEVICES", "")
 if _n_host.isdigit() and int(_n_host) > 1:
     _flags = os.environ.get("XLA_FLAGS", "")
@@ -112,7 +95,7 @@ if _n_host.isdigit() and int(_n_host) > 1:
 
 import jax
 import jax.numpy as jnp
-from jax.experimental import enable_x64
+from jax import enable_x64
 
 from ..trace_ir import CPU, CompiledTrace
 from .arrivals import HIST_BINS, LatencySummary, hist_bin_value
@@ -165,7 +148,7 @@ class TraceArrays:
         ends[:n_ops] = trace.bounds[1:]
         starts[n_ops:] = trace.bounds[-2]    # replicate the last op; the
         ends[n_ops:] = trace.bounds[-1]      # replay never reads past n_ops
-        with enable_x64():
+        with enable_x64(True):
             return cls(jnp.asarray(kinds), jnp.asarray(durs),
                        jnp.asarray(starts), jnp.asarray(ends),
                        n_ops, n_subops)
@@ -294,6 +277,14 @@ def _make_flags(cfg: SimConfig) -> dict:
 _RNG_CHUNK = 1024   # steps per generated uniform block (memory/dispatch knob)
 
 
+def _uniform(key, shape):
+    """``jax.random.uniform(key, shape, float64)``, bit for bit, without
+    its u64 -> f64 bitcast (which XLA:TPU's float64 emulation cannot
+    rewrite): the top 52 of 64 random bits, scaled into [0, 1)."""
+    bits = jax.random.bits(key, shape, jnp.uint64)
+    return (bits >> 12).astype(jnp.float64) * 2.0 ** -52
+
+
 def _grid_body(kinds, durs, op_starts, op_ends, n_trace,
                L_mem_g, nthr_g, warm_g, n_ops, dyn, key, stream_ids, arr, *,
                T_max, P, n_ssd, steps, unroll, substeps, use_pallas,
@@ -347,8 +338,8 @@ def _grid_body(kinds, durs, op_starts, op_ends, n_trace,
     tids = jnp.arange(CT, dtype=i4)
     t_local = tids % T_max                 # slot within the owning core
     active = t_local[None, :] < nthr_g[:, None]                # (G, CT)
-    u_cursor = jax.vmap(lambda k: jax.random.uniform(
-        jax.random.fold_in(k, 0), (), dtype=f))(cell_keys)
+    u_cursor = jax.vmap(lambda k: _uniform(
+        jax.random.fold_in(k, 0), ()))(cell_keys)
     cursor0 = jnp.floor(u_cursor * n_trace).astype(i4)
     # Active threads consume consecutive cursor slots in core-major tid
     # order, like the loops' init (padding slots alias harmlessly: they
@@ -357,8 +348,8 @@ def _grid_body(kinds, durs, op_starts, op_ends, n_trace,
     opidx0 = (cursor0[:, None] + rank) % n_trace
     cursor_init = (cursor0 + n_cores * nthr_g) % n_trace
     u_thread = jax.vmap(lambda k: jax.vmap(
-        lambda t: jax.random.uniform(jax.random.fold_in(k, 2 + t), (2,),
-                                     dtype=f))(tids))(cell_keys)  # (G, CT, 2)
+        lambda t: _uniform(jax.random.fold_in(k, 2 + t), (2,)))(tids)
+    )(cell_keys)                                                 # (G, CT, 2)
     pf0 = u_thread[:, :, 0] * lmem(u_thread[:, :, 1], L_mem_g[:, None])
     if has_arr:
         # Open loop: thread ``rank`` takes arrival index ``rank`` (the
@@ -375,14 +366,11 @@ def _grid_body(kinds, durs, op_starts, op_ends, n_trace,
         parked0 = jnp.zeros_like(active)
 
     # Initial state, in the sched_step layout: active threads populate the
-    # ready ring in tid order (join stamps sit an EPOCH apart just above
-    # time zero -- normal floats, so FTZ cannot collapse them -- and the
-    # tag bits carry the tid), parked/inactive slots hold the BIG
-    # sentinel / +inf.
+    # ready ring in tid order (equal INIT_KEY stamps, which break toward
+    # the lower tid), parked/inactive slots hold the BIG sentinel / +inf,
+    # and the prefetch window starts empty (all slots free at time zero).
     span0 = sk.pack_span(op_starts[opidx0].astype(f),
                          op_ends[opidx0].astype(f))
-    tids_gt = jnp.broadcast_to(tids[None, :], (G, CT))
-    slots_p = jnp.arange(P, dtype=i4)[None, :]
     pf_shape = (G, n_cores, P) if multicore else (G, P)
     ci_cols = [cursor_init, jnp.zeros(G, i4), jnp.zeros(G, i4),
                jnp.zeros(G, i4), jnp.zeros(G, i4),
@@ -395,15 +383,11 @@ def _grid_body(kinds, durs, op_starts, op_ends, n_trace,
     state = (
         jnp.zeros((G, 6), f).at[:, 3].set(-1.0),
         jnp.stack(ci_cols, axis=1),
-        jnp.where(active & ~parked0,
-                  sk.tag_encode(tids_gt.astype(f) * sk.EPOCH, tids_gt),
-                  sk.BIG),
+        jnp.where(active & ~parked0, sk.INIT_KEY, sk.BIG),
         (jnp.where(parked0, arr0, jnp.inf) if has_arr
          else jnp.full((G, CT), jnp.inf, f)),
         jnp.stack(pft_cols, axis=2),
-        jnp.broadcast_to((slots_p.astype(f) * sk.EPOCH)
-                         .reshape((1,) * (len(pf_shape) - 1) + (P,)),
-                         pf_shape),
+        jnp.zeros(pf_shape, f),
     )
     if multicore:
         state = state + (jnp.zeros((G, n_cores, 2), f),)
@@ -431,10 +415,9 @@ def _grid_body(kinds, durs, op_starts, op_ends, n_trace,
 
     def chunk(s, ck):
         if n_u:
-            us = jax.vmap(lambda k: jax.random.uniform(
-                jax.random.fold_in(k, ck), (_RNG_CHUNK, n_u),
-                dtype=f))(k_chunks)              # (G, CH, n_u), per cell
-            us = jnp.moveaxis(us, 0, -1)         # (CH, n_u, G)
+            us = jax.vmap(lambda k: _uniform(            # per cell
+                jax.random.fold_in(k, ck), (_RNG_CHUNK, n_u)))(k_chunks)
+            us = jnp.moveaxis(us, 0, -1)         # (G, CH, n_u) -> (CH, n_u, G)
         else:
             us = jnp.zeros((_RNG_CHUNK, 0, G), f)
         if use_pallas:
@@ -500,26 +483,21 @@ def _run_grid_sharded(n_dev: int, **static):
     axis over ``n_dev`` host CPU devices (the caller pads G to a multiple).
     Each shard runs -- and early-exits -- independently: there are no
     collectives in the grid program."""
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
-    devs = jax.devices("cpu")[:n_dev]
-    if hasattr(jax, "make_mesh"):
-        mesh = jax.make_mesh((n_dev,), ("cells",), devices=devs)
-    else:  # older jax: build the mesh directly
-        from jax.sharding import Mesh
-        mesh = Mesh(np.asarray(devs), ("cells",))
+    mesh = jax.make_mesh((n_dev,), ("cells",),
+                         devices=jax.devices("cpu")[:n_dev])
     cells, repl = P("cells"), P()
-    fn = shard_map(
-        partial(_grid_body, **static), mesh,
+    fn = jax.shard_map(
+        partial(_grid_body, **static), mesh=mesh,
         in_specs=(repl, repl, repl, repl, repl,      # trace columns, n_trace
                   cells, cells, cells,               # L_mem_g, nthr_g, warm_g
                   repl, repl, repl, cells,           # n_ops, dyn, key, streams
                   repl),                             # arrival timestamps
         out_specs=cells,
-        # the early-exit while_loop has no replication rule; every output
-        # is cell-sharded anyway, so the rep check buys nothing here
-        check_rep=False,
+        # every output is cell-sharded and nothing is replicated across
+        # shards, so the varying-axes check buys nothing here
+        check_vma=False,
     )
     return jax.jit(fn)
 
@@ -591,13 +569,14 @@ def sweep_grid(
     ``cfg`` supplies everything except ``L_mem``/``n_threads`` (the grid
     axes); ``n_threads`` is *per core*, and ``cfg.n_cores > 1`` replays
     the multi-core scheduler (per-core rings + prefetch windows, shared
-    T_lock / SSD clocks) as long as ``n_cores * T_max`` fits the tag bits.
-    Scalar latencies only; ``warmup_ops`` defaults per cell to
-    ``2 * n_threads * n_cores``, like the loop backends.
+    T_lock / SSD clocks).  Scalar latencies only; ``warmup_ops`` defaults
+    per cell to ``2 * n_threads * n_cores``, like the loop backends.
 
     ``use_pallas`` routes the scan through the fused whole-step kernel
-    (``substeps`` inner steps per kernel invocation); the default jnp scan
-    path uses ``unroll`` to amortize dispatch instead.
+    (``substeps`` inner steps per kernel invocation); it runs on the CPU
+    backend only (interpreted), and raises elsewhere because the kernel
+    does not compile for TPU yet.  The default jnp scan path uses
+    ``unroll`` to amortize dispatch instead.
     ``bucket_threads=False`` forces the single monolithic layout (all
     candidates padded to one ``T_max``, one global step bound);
     ``early_exit=False`` additionally scans every cohort to its full
@@ -607,8 +586,10 @@ def sweep_grid(
     ``host_devices=N > 1`` shard_maps each cohort's cell axis over N XLA
     host CPU devices (export ``REPRO_JAX_HOST_DEVICES=N`` -- or set
     ``--xla_force_host_platform_device_count`` -- *before* jax
-    initializes); shards early-exit independently.  Incompatible with
-    ``use_pallas`` (the interpreted kernel cannot run under shard_map).
+    initializes); shards early-exit independently.  It raises unless the
+    default backend is CPU, so it can never move an accelerator's grid
+    onto the host, and is incompatible with ``use_pallas`` (the
+    interpreted kernel cannot run under shard_map).
 
     ``arrivals`` (a monotone timestamp sequence, seconds -- see
     :func:`repro.core.sim.arrivals.generate_arrivals`) switches every
@@ -645,7 +626,7 @@ def sweep_grid(
             f"substeps must divide the RNG chunk ({_RNG_CHUNK}): "
             f"{substeps}")
 
-    from repro.kernels.sched_step import SPAN_SHIFT, TAG_BITS
+    from repro.kernels.sched_step import SPAN_SHIFT
 
     source = trace if isinstance(trace, CompiledTrace) else trace.to_trace()
     ta = trace if isinstance(trace, TraceArrays) else lower_trace(trace)
@@ -654,16 +635,23 @@ def sweep_grid(
             f"trace has {int(ta.op_ends[-1])} suboperations; the fused "
             f"step's span packing supports < 2**{SPAN_SHIFT}")
     n_lat, n_cand = len(latencies), len(candidates)
-    if cfg.n_cores * max(candidates) > (1 << TAG_BITS):
+    backend = jax.default_backend()
+    if use_pallas and backend != "cpu":
         raise ValueError(
-            f"n_cores * max threads = {cfg.n_cores * max(candidates)} "
-            f"exceeds the {1 << TAG_BITS} thread slots the tag encoding "
-            f"supports (TAG_BITS={TAG_BITS}); use backend='loop' for "
-            "wider machines")
+            f"use_pallas=True cannot run on the {backend} backend: the "
+            "fused sched_step kernel keeps its scheduler state in float64, "
+            "which Mosaic does not lower inside a Pallas kernel (moving "
+            "time to a 32-bit encoding is ROADMAP Queue 1 item 3); use the "
+            "default jnp scan (use_pallas=False)")
     n_dev = 1 if host_devices is None else int(host_devices)
     if n_dev < 1:
         raise ValueError(f"host_devices must be >= 1, got {host_devices}")
     if n_dev > 1:
+        if backend != "cpu":
+            raise ValueError(
+                f"host_devices={n_dev} shards the grid over host CPU "
+                f"devices, but the default backend is {backend}: drop "
+                "host_devices to run the grid on the accelerator")
         if use_pallas:
             raise ValueError(
                 "host_devices > 1 cannot run the interpreted Pallas "
@@ -727,7 +715,12 @@ def sweep_grid(
     max_steps = 0
     steps_bound_cells = 0
     steps_run_cells = 0
-    with enable_x64():
+    # The draws come from threefry in its non-partitionable bit layout: the
+    # stream every tolerance contract, test bound and conformance-corpus
+    # entry was measured on (JAX 0.5 made the partitionable layout the
+    # default; per-cell draws never span devices, so its sharding benefit
+    # does not apply here).
+    with enable_x64(True), jax.threefry_partitionable(False):
         for cols, T_max, steps in cohorts:
             cand_b = [candidates[j] for j in cols]
             nc = len(cand_b)

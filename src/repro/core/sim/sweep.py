@@ -483,11 +483,12 @@ def sweep_latency(
         Jax-backend execution tuning, forwarded to
         :func:`~repro.core.sim.replay_jax.sweep_grid`: ``use_pallas``
         routes the scan through the fused whole-step kernel (``substeps``
-        inner steps per kernel invocation), ``unroll`` amortizes dispatch
-        on the jnp scan path, ``host_devices`` shard_maps the cell axis
-        over that many host CPU devices (requires the process to have been
-        started with ``--xla_force_host_platform_device_count``).  ``None``
-        keeps ``sweep_grid``'s default.  Strategy knobs only -- cell
+        inner steps per kernel invocation; CPU interpreter only, refused
+        on other backends), ``unroll`` amortizes dispatch on the jnp scan
+        path, ``host_devices`` shard_maps the cell axis over that many
+        host CPU devices (CPU backend only; requires the process to have
+        been started with ``--xla_force_host_platform_device_count``).
+        ``None`` keeps ``sweep_grid``'s default.  Strategy knobs only -- cell
         values (and hence cache keys) do not depend on them; ignored by
         ``backend="loop"``.
     arrival

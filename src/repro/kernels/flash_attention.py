@@ -22,8 +22,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .compat import CompilerParams
-
 __all__ = ["flash_attention"]
 
 NEG_INF = -1e30
@@ -114,7 +112,7 @@ def flash_attention(
             pltpu.VMEM((block_q, 1), jnp.float32),      # l
             pltpu.VMEM((block_q, D), jnp.float32),      # acc
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

@@ -15,9 +15,12 @@ factored so the exact same arithmetic runs two ways:
     substeps per kernel invocation (the ``substeps`` knob), so the planes
     do not round-trip through HBM between steps.
 
-The TPU is the compile target; on CPU the kernel runs in ``interpret=True``
-mode (the :mod:`repro.kernels.compat` convention), which is how CI validates
-it bit-for-bit against the jnp path on tiny grids
+On TPU the jnp scan is the path that compiles: the fused kernel does not,
+because its state is float64 and Mosaic lowers no 64-bit operand inside a
+Pallas kernel (``replay_jax.sweep_grid`` refuses ``use_pallas`` there; the
+32-bit time encoding that would lift this is ROADMAP Queue 1 item 3).  On
+CPU the kernel runs in ``interpret=True`` mode, which is how the tests
+validate it bit-for-bit against the jnp path on tiny grids
 (``tests/test_replay_jax.py``).  Bit-identity holds by construction: both
 paths execute ``make_substep``'s ops in the same order; the kernel variant
 only switches the per-row gather/scatter *implementation* to one-hot
@@ -25,19 +28,19 @@ select/merge forms (``onehot_updates``), which produce bit-identical values
 (a one-term masked sum is exact) while staying on the VPU-friendly subset
 of ops.
 
-Tag-encoded minima
-------------------
-``argmin`` is several times the cost of ``min`` on every backend we care
-about (and the old step needed four of them).  Instead, every plane that is
-reduced to "earliest entry + which thread" stores its key with the entry
-*index* packed into the low :data:`TAG_BITS` bits of the float64 mantissa
-(:func:`tag_encode`): a single ``min`` reduction then returns the winning
-key and its index together (:func:`tag_tid`).  Keys are non-negative
-simulated-time stamps whose meaningful differences (>= nanoseconds on a
-seconds-scale clock) dwarf the ``2**TAG_BITS``-ulp tag perturbation, so
-the encoding never reorders distinct keys; exact ties break toward the
-lower index, matching ``argmin`` -- and matching the scalar loop's
-lowest-tid-first drain of simultaneous IO completions.
+Lexicographic minima
+--------------------
+Every plane that is reduced to "earliest entry + which slot" goes through
+:func:`ring_min`: one ``min`` over the exact keys, then one ``min`` over
+the slot indices that attain it.  Keys are never altered, so distinct
+times never tie, and exact ties break toward the lower index -- except in
+the ready ring, where a thread derived from the wake plane beats a
+re-entrant runner's ticket at the same instant (the loops drain wake-ups
+at iteration start, before the runner re-joins).  Nothing here reads a
+float's bits: XLA:TPU emulates float64 and cannot rewrite a 64-bit
+``bitcast_convert_type``, and its emulation keeps float32's exponent range
+(normals below ``2**-126`` flush to zero), so the scheduler also uses no
+sub-normal-magnitude spacing constants.
 
 State layout (the kernel ref contract)
 --------------------------------------
@@ -53,11 +56,11 @@ State layout (the kernel ref contract)
                               5 measuring flag
   ``stamp``     (G, T) f64    ready threads' ring ticket: the *pop time*
                               at which the thread last started a
-                              suboperation (tag-encoded with the tid);
+                              suboperation (``INIT_KEY`` for the initial
+                              ring, which sorts ahead of any pop);
                               ``BIG`` when parked or inactive
-  ``wake``      (G, T) f64    parked threads' IO completion time, stored
-                              *exact* (the idle-skip reads it back as a
-                              time; ``ring_keys`` tags it on the fly);
+  ``wake``      (G, T) f64    parked threads' IO completion time (the
+                              idle-skip reads it back as a time);
                               ``+inf`` when ready or inactive.  Threads
                               whose IO completed are derived into the
                               ring at pop time, never written back
@@ -65,20 +68,21 @@ State layout (the kernel ref contract)
                               1 trace span ``end * 2**SPAN_SHIFT + i``
                               (both integers < 2**SPAN_SHIFT: exact)
   ``pf_slots``  (G, P) f64    P-deep in-flight prefetch window completion
-                              times, stored exact (the all-busy delay
-                              reads the minimum back as a time; the slot
-                              pick tags on the fly)
+                              times (the all-busy delay reads the minimum
+                              back as a time; ties pick the lower slot)
   ``io_tok``    (G, S) f64    per-device IOPS token clocks (clock configs)
   ``io_bw``     (G, S) f64    per-device bandwidth token clocks
   ============  ============  =================================================
 
 With ``n_cores = C > 1`` (see :func:`make_substep`) the thread planes hold
-``T = C * T_per_core`` core-major slots tagged by *global* tid,
+``T = C * T_per_core`` core-major slots indexed by *global* tid,
 ``pf_slots`` becomes ``(G, C, P)``, and one extra plane ``cores``
 ``(G, C, 2)`` (0 local clock, 1 prefetch-bw clock) sits between
 ``pf_slots`` and the IO clocks; ``cf[:, 0]``/``cf[:, 1]`` then carry the
 global drain horizon (running max of pop times, mirroring the loop's
-shared parked heap -- see the in-step comment) / nothing.
+shared parked heap -- see the in-step comment) / nothing.  The thread
+index of a slot is its column, so no plane stores thread ids and the
+thread count has no encoding limit.
 
 The K-substep batching contract: one :func:`fused_steps` invocation consumes
 a ``(K, n_u, G)`` block of pre-drawn uniforms and advances the state by
@@ -88,8 +92,6 @@ blocks of K is step-for-step identical to a scan over single steps.
 """
 from __future__ import annotations
 
-import numpy as np
-
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
@@ -98,55 +100,40 @@ from ..core.sim.arrivals import HIST_BINS, HIST_INV_LN_RATIO, HIST_LO
 from ..core.trace_ir import MEM, PREIO
 
 __all__ = [
-    "TAG_BITS", "SPAN_SHIFT", "BIG", "EPOCH", "tag_encode", "tag_tid",
-    "tag_value",
+    "SPAN_SHIFT", "BIG", "INIT_KEY", "ring_min",
     "pack_span", "unpack_span", "make_substep", "fused_steps",
 ]
 
-TAG_BITS = 8                       # index bits packed into the mantissa
-_TAG_MASK = np.uint64((1 << TAG_BITS) - 1)
-_KEY_MASK = np.uint64(~_TAG_MASK & 0xFFFFFFFFFFFFFFFF)
-
 # Sentinel for "no entry" (parked/inactive threads in the stamp plane).
-# Finite -- not inf -- so it tag-decodes to thread 0 instead of garbage;
-# real stamps stay far below it.
-BIG = float(
-    (np.float64(1e30).view(np.uint64) & _KEY_MASK).view(np.float64))
+# Finite, and inside float32's range: XLA:TPU's emulated float64 turns
+# larger magnitudes into inf/NaN.
+BIG = 1e30
+
+# Ring key of the initial ready threads: below every pop-time ticket, a
+# pop at time zero included, so the first runner re-enters behind the
+# untouched initial ring; the equal keys break toward the lower tid, the
+# loops' initial deque order.
+INIT_KEY = -1.0
 
 SPAN_SHIFT = 26                    # pft span packing: end*2**26 + i, exact
 _SPAN = float(1 << SPAN_SHIFT)     # in f64 while both stay below 2**26
 _INV_SPAN = 1.0 / _SPAN
 
-# Spacing for "time zero, position k" init keys.  The CPU runtimes run
-# with FTZ/DAZ, so a denormal key (e.g. raw-bits ``k``) silently compares
-# equal to 0.0 and the tagged min collapses every initial ring slot onto
-# index 0.  Spacing by the smallest *normal* f64 keeps the init keys
-# ordered, distinct, flush-proof, and far below any real simulated time.
-EPOCH = float(np.finfo(np.float64).tiny)
 
+def ring_min(keys, wake_first=None):
+    """Row-wise lexicographic minimum of a ``(G, N)`` key plane.
 
-def tag_encode(key, idx):
-    """Pack ``idx`` into the low :data:`TAG_BITS` mantissa bits of ``key``.
-
-    ``key`` must be non-negative and distinct keys must differ by more than
-    ``2**TAG_BITS`` ulps for the order to survive (see module docstring).
+    Returns ``(key, idx)``: the smallest key of each row and the column
+    that holds it.  Equal keys break toward columns flagged in the boolean
+    ``wake_first`` plane, then toward the lower column.
     """
-    bits = jax.lax.bitcast_convert_type(key, jnp.uint64)
-    tag = idx.astype(jnp.uint64) & _TAG_MASK
-    return jax.lax.bitcast_convert_type((bits & _KEY_MASK) | tag,
-                                        jnp.float64)
-
-
-def tag_tid(enc):
-    """The index packed by :func:`tag_encode` (int32)."""
-    bits = jax.lax.bitcast_convert_type(enc, jnp.uint64)
-    return (bits & _TAG_MASK).astype(jnp.int32)
-
-
-def tag_value(enc):
-    """The key with its tag bits cleared (a 256-ulp floor of the original)."""
-    bits = jax.lax.bitcast_convert_type(enc, jnp.uint64)
-    return jax.lax.bitcast_convert_type(bits & _KEY_MASK, jnp.float64)
+    n = keys.shape[1]
+    kmin = jnp.min(keys, axis=1)
+    col = jax.lax.broadcasted_iota(jnp.int32, keys.shape, 1)
+    if wake_first is not None:
+        col = jnp.where(wake_first, col, col + n)
+    code = jnp.min(jnp.where(keys == kmin[:, None], col, 2 * n), axis=1)
+    return kmin, jnp.where(code >= n, code - n, code)
 
 
 def pack_span(start, end):
@@ -201,16 +188,14 @@ def make_substep(*, n_u, n_ssd, has_eps, has_rho, has_jitter, has_rio,
     identical either way).
 
     ``n_cores > 1`` adds a core axis: thread planes become ``(G, C*T)``
-    core-major with *global* tids in the tag bits (so ``C*T`` must stay
-    <= 2**TAG_BITS), the prefetch window and its bandwidth clock become
-    per-core (``pf_slots`` is ``(G, C, P)``, and a new ``cores`` plane
+    core-major (the column is the global tid), the prefetch window and
+    its bandwidth clock become per-core (``pf_slots`` is ``(G, C, P)``,
+    and a new ``cores`` plane
     ``(G, C, 2)`` carries each core's local clock and prefetch-bw clock),
     while the trace cursor, op counters, T_lock clock, and SSD token
     clocks stay shared -- exactly the generic loop's sharing.  Each step
-    first picks the core with the earliest next-event time (its local
-    clock if it has a runnable thread, else its earliest parked wake --
-    the loop's core heap + idle-skip collapsed into one tagged min; ties
-    break to the lower core id like ``heapq``) and then runs the
+    first picks the core with the earliest yield clock (the loop's core
+    heap; ties break to the lower core id like ``heapq``) and then runs the
     single-core step body on that core's thread segment.  The
     ``n_cores == 1`` path is byte-for-byte the pre-existing substep.
     """
@@ -272,7 +257,7 @@ def make_substep(*, n_u, n_ssd, has_eps, has_rho, has_jitter, has_rio,
         reached = counted0 >= n_ops    # cell already took its last op
 
         if multicore:
-            # -- core selection: the loop's core heap as one tagged min -----
+            # -- core selection: the loop's core heap -----------------------
             # Heap entries are the cores' clocks at their last *yield*, NOT
             # their next-event times: the loop pops the core whose last run
             # ended earliest, and a core popped with an empty ring jumps
@@ -280,12 +265,9 @@ def make_substep(*, n_u, n_ssd, has_eps, has_rho, has_jitter, has_rio,
             # never re-enters the heap re-keyed.  So selection compares the
             # yield clocks, and the idle-skip applies only to the *selected*
             # core (the single-core path per core segment).  The scan is an
-            # exact unrolled min (C is small and static): cores running the
-            # same ops sit within a few ulps of each other, well inside the
-            # 2**TAG_BITS quantum, so a tag-encoded min would collapse
-            # distinct clocks into ties and pick the wrong core.  Strict
-            # ``<`` breaks ties to the lower cid, exactly ``heapq``'s
-            # (t, cid) entries.
+            # exact unrolled min (C is small and static); strict ``<``
+            # breaks ties to the lower cid, exactly ``heapq``'s (t, cid)
+            # entries.
             C, Tpc = n_cores, T // n_cores
             core_now = cores[:, :, 0]                        # (G, C)
             wake3 = wake.reshape(G, C, Tpc)
@@ -298,88 +280,64 @@ def make_substep(*, n_u, n_ssd, has_eps, has_rho, has_jitter, has_rio,
                 cstar = jnp.where(earlier, c, cstar)
                 now = jnp.where(earlier, cand, now)
             # The selected core's ring head / idle-skip, exactly the
-            # single-core derivation over its thread segment; tags are
-            # global tids, so ``tid`` indexes the flat planes directly.
+            # single-core derivation over its thread segment; the local
+            # column plus the core's offset is the global tid.
             wake_c = sel_thread(wake3, cstar)                # (G, Tpc)
             stamp_c = sel_thread(stamp3, cstar)
-            gtid_c = (cstar[:, None] * Tpc
-                      + jax.lax.broadcasted_iota(i4, (G, Tpc), 1))
-
-            def ring_keys_mc(now_v):
-                wkey = tag_encode(
-                    jnp.maximum(jnp.minimum(wake_c, BIG), T * EPOCH), gtid_c)
-                return jnp.where(wake_c <= now_v[:, None], wkey, stamp_c)
-
-            head = jnp.min(ring_keys_mc(now), axis=1)
-            starved = head >= BIG
-
-            def skip_mc(now_v):
-                w_min = jnp.min(wake_c, axis=1)
-                now2 = jnp.where(starved, jnp.maximum(now_v, w_min), now_v)
-                return now2, jnp.min(ring_keys_mc(now2), axis=1)
-
-            if eager_wmin:
-                now, head = skip_mc(now)
-            else:
-                now, head = jax.lax.cond(
-                    jnp.any(starved), lambda: skip_mc(now),
-                    lambda: (now, head))
-            pop_now = now
+            ring_wake, ring_stamp = wake_c, stamp_c
         else:
             now = cf[:, 0]
+            ring_wake, ring_stamp = wake, stamp
 
-            # -- pop the ring head: one tag-encoded min replaces argmin -----
-            # Ring stamps are *entry tickets*: a thread re-enters the ring
-            # keyed by its pop time, and a parked thread whose IO completed
-            # joins at its wake time -- so the FIFO order is just time
-            # order, and parked-but-complete threads can be *derived* into
-            # the ring at pop time instead of being written back.  The key
-            # plane below stays a temporary the backend fuses into the min
-            # reduction; the materialized wake drain it replaces (two
-            # carried full-plane writes per step) was the single largest
-            # cost of the old step.
-            tids_row = jax.lax.broadcasted_iota(i4, (G, T), 1)
+        # -- pop the ring head: a lexicographic min replaces the deque ------
+        # Ring stamps are *entry tickets*: a thread re-enters the ring keyed
+        # by its pop time, and a parked thread whose IO completed joins at
+        # its wake time -- so the FIFO order is just time order, and
+        # parked-but-complete threads can be *derived* into the ring at pop
+        # time instead of being written back.  The key plane below stays a
+        # temporary the backend fuses into the reductions; the materialized
+        # wake drain it replaces (two carried full-plane writes per step)
+        # was the single largest cost of the old step.
+        def ring_head(now_v):
+            woken = ring_wake <= now_v[:, None]
+            return ring_min(jnp.where(woken, ring_wake, ring_stamp), woken)
 
-            def ring_keys(now_v):
-                wkey = tag_encode(
-                    jnp.maximum(jnp.minimum(wake, BIG), T * EPOCH), tids_row)
-                return jnp.where(wake <= now_v[:, None], wkey, stamp)
+        head, slot_tid = ring_head(now)
 
-            head = jnp.min(ring_keys(now), axis=1)
+        # -- idle-skip: nothing ready, nothing eligible -> jump to the ------
+        # earliest wake-up and re-derive the keys.  Starvation is rare for
+        # healthy thread counts, so the jnp path branches around the second
+        # pass at run time; the kernel path runs it straight-line.  The
+        # values agree either way: a cell that did not starve re-derives
+        # identical keys from an unchanged ``now``.
+        starved = head >= BIG
 
-            # -- idle-skip: nothing ready, nothing eligible -> jump to the --
-            # earliest wake-up and re-derive the keys.  Starvation is rare
-            # for healthy thread counts, so the jnp path branches around the
-            # second pass at run time; the kernel path runs it
-            # straight-line.  The values agree either way: a cell that did
-            # not starve re-derives identical keys from an unchanged
-            # ``now``.
-            starved = head >= BIG
+        def skip(now_v):
+            w_min = jnp.min(ring_wake, axis=1)
+            now2 = jnp.where(starved, jnp.maximum(now_v, w_min), now_v)
+            return (now2,) + ring_head(now2)
 
-            def skip(now_v):
-                w_min = jnp.min(wake, axis=1)
-                now2 = jnp.where(starved, jnp.maximum(now_v, w_min), now_v)
-                return now2, jnp.min(ring_keys(now2), axis=1)
-
-            if eager_wmin:
-                now, head = skip(now)
-            else:
-                now, head = jax.lax.cond(
-                    jnp.any(starved), lambda: skip(now), lambda: (now, head))
-        tid = tag_tid(head)
+        if eager_wmin:
+            now, head, slot_tid = skip(now)
+        else:
+            now, head, slot_tid = jax.lax.cond(
+                jnp.any(starved), lambda: skip(now),
+                lambda: (now, head, slot_tid))
+        if multicore:
+            pop_now = now
+            tid = cstar * Tpc + slot_tid
+        else:
+            tid = slot_tid
         # The popped thread's next ring ticket.  The scalar loop drains
         # wake-ups only at iteration start, *after* the previous runner
         # re-joined the deque -- so a thread woken during the runner's
-        # execution window queues behind it.  Keying the re-entrant
-        # runner by its pop time (not its yield time) reproduces that
-        # order exactly: wakes <= pop time drained at or before this
-        # iteration and sort ahead; later wakes sort behind.  The key is
-        # clamped to T*EPOCH -- above every init stamp, still a normal
-        # float -- because a pop at time zero (every core's first pop)
-        # would otherwise store a denormal ticket that FTZ/DAZ runtimes
-        # read back as 0.0 with tag 0, re-running the popped thread ahead
-        # of the untouched ring instead of appending it at the tail.
-        ticket = tag_encode(jnp.maximum(now, T * EPOCH), tid)
+        # execution window queues behind it.  Keying the re-entrant runner
+        # by its pop time (not its yield time) reproduces that order
+        # exactly: wakes before the pop time drained at or before this
+        # iteration and sort ahead; later wakes sort behind; a wake *at*
+        # the pop time was drained at the start of this very iteration,
+        # which is why ``ring_min`` breaks that tie toward the woken thread.
+        ticket = now
 
         pft_r = sel_thread(pft, tid)                 # (G, 2) or (G, 3)
         pf_tid0 = pft_r[:, 0]
@@ -505,15 +463,7 @@ def make_substep(*, n_u, n_ssd, has_eps, has_rho, has_jitter, has_rio,
         else:
             slots_row = pf_slots
             pf_bw = cf[:, 1]
-        # Slots store *exact* completion times; the tagged key exists only
-        # inside the min reduction, so the all-busy delay below is computed
-        # from the true float (tag-flooring it drifts ~256 ulps per issue,
-        # which compounds over long runs).  The EPOCH clamp keeps the
-        # time-zero init keys normal under FTZ/DAZ.
-        slot_iota = jax.lax.broadcasted_iota(i4, slots_row.shape, 1)
-        slot = tag_tid(jnp.min(
-            tag_encode(jnp.maximum(slots_row, EPOCH), slot_iota), axis=1))
-        slot_min = sel_thread(slots_row, slot)
+        slot_min, slot = ring_min(slots_row)
         if has_arr:
             # Open loop: a not-yet-arrived op issues at its arrival clock
             # (post-T_lock now, exactly the loops' max(now, arrival)).
@@ -550,10 +500,6 @@ def make_substep(*, n_u, n_ssd, has_eps, has_rho, has_jitter, has_rio,
             wake_val = jnp.where(park, jnp.maximum(park_until, now),
                                  jnp.inf)
         stamp = upd_thread(stamp, tid, jnp.where(parked_any, BIG, ticket))
-        # Wake times are stored exact (no tag): the starved idle-skip and
-        # the eligibility compare read them back as *times*, and a tagged
-        # store would perturb those reads by up to 2**TAG_BITS ulps per
-        # park.  ``ring_keys`` re-tags on the fly for the pop ordering.
         wake = upd_thread(wake, tid, wake_val)
         pft_cols = [pf_tid, span_next]
         if has_lat:
@@ -572,28 +518,19 @@ def make_substep(*, n_u, n_ssd, has_eps, has_rho, has_jitter, has_rio,
             # clock, before their IO completion time.  ``cf[:, 0]`` carries
             # that horizon H (the running max of pop times); threads whose
             # wake fell at or below H while still above their core's clock
-            # are materialized into the stamp plane here, ticketed at their
-            # core's current clock (the ring-tail position the loop's
-            # append gives them).  Threads whose wake is at or below their
-            # own clock stay derived (key = wake) as in the single-core
-            # path.
+            # have their wake pulled down to that clock here, so they are
+            # derived into the ring at the ring-tail position the loop's
+            # append gives them.  The loop appends the drained thread
+            # before the core's next pop, whose runner re-enters ticketed
+            # at that same clock value: ``ring_min``'s woken-first tie
+            # break keeps the drained thread ahead of it.  Threads whose
+            # wake is at or below their own clock stay derived (key =
+            # wake) as in the single-core path.
             H = jnp.maximum(cf[:, 0], pop_now)
             clock_t = jnp.broadcast_to(
                 cores[:, :, 0][:, :, None], (G, C, Tpc)).reshape(G, T)
-            tids_all = jax.lax.broadcasted_iota(i4, (G, T), 1)
             early = (wake <= H[:, None]) & (wake > clock_t)
-            # Ticket one tag-grid step *below* the core clock: the loop
-            # appends the drained thread before the core's next pop, whose
-            # runner re-enters ticketed at that same clock value -- the
-            # bias keeps the drained thread strictly ahead of it.  Real
-            # pops sit >= T_sw apart, far more than one grid step, so the
-            # bias cannot cross an earlier ticket.
-            cbits = jax.lax.bitcast_convert_type(
-                jnp.maximum(clock_t, 2.0 * T * EPOCH), jnp.uint64)
-            tail_key = jax.lax.bitcast_convert_type(
-                cbits - jnp.uint64(1 << TAG_BITS), jnp.float64)
-            stamp = jnp.where(early, tag_encode(tail_key, tids_all), stamp)
-            wake = jnp.where(early, jnp.inf, wake)
+            wake = jnp.where(early, clock_t, wake)
             # The loop reports elapsed time against the *latest* core clock
             # at exit (``max(c.now for c in cores)``).
             t_end = jnp.where(crossed, jnp.max(cores[:, :, 0], axis=1),
@@ -631,8 +568,10 @@ def fused_steps(substep, state, u_block, kd, se, arr, n_trace, L_mem_g,
     once, carried through an in-kernel ``fori_loop`` over the K substeps,
     and written back once, so on a compiled backend the scheduler state
     never leaves VMEM between substeps.  ``interpret=None`` auto-selects
-    interpreter mode off-TPU (CPU CI validates bit-identity against the
-    jnp scan path this way).
+    interpreter mode off-TPU, which is how the CPU tests validate
+    bit-identity against the jnp scan path.  The kernel does not compile
+    for TPU yet: its planes and scalars are float64, which Mosaic does
+    not lower (see the module docstring).
     """
     if interpret is None:
         interpret = jax.default_backend() != "tpu"

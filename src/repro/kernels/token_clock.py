@@ -14,9 +14,8 @@ form is pure VPU work over ``(n_cells, n_ssd)`` clock arrays:
     scalar loops' ``svc = max(svc, tok); tok = svc + 1/R_io`` order exactly;
   * clocks of unselected devices pass through unchanged.
 
-The TPU is the target; on CPU the kernel runs in ``interpret=True`` mode
-(the :mod:`repro.kernels.compat` convention), which is how CI validates it
-against :func:`token_clock_update_ref` -- the pure-jnp twin used by the jax
+The TPU is the target; on CPU the kernel runs in ``interpret=True`` mode,
+which is how the tests validate it against :func:`token_clock_update_ref` -- the pure-jnp twin used by the jax
 backend's default (non-Pallas) path.  Both paths are bit-identical: same
 ops, same order.
 """

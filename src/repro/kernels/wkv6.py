@@ -21,8 +21,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .compat import CompilerParams
-
 __all__ = ["wkv6"]
 
 
@@ -84,7 +82,7 @@ def wkv6(
         out_specs=pl.BlockSpec(blk, spec),
         out_shape=jax.ShapeDtypeStruct((B, S, H, D), r.dtype),
         scratch_shapes=[pltpu.VMEM((D, D), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

@@ -174,11 +174,8 @@ def test_crosspod_compressed_psum():
             out = crosspod(q, s)
             return out["g"][None]
 
-        shard_map = getattr(jax, "shard_map", None)
-        if shard_map is None:  # pre-0.6 jax keeps it in experimental
-            from jax.experimental.shard_map import shard_map
-        f = shard_map(per_pod, mesh=mesh,
-                      in_specs=P("pod", None), out_specs=P("pod", None))
+        f = jax.shard_map(per_pod, mesh=mesh,
+                          in_specs=P("pod", None), out_specs=P("pod", None))
         got = f(g_global)
         want = jnp.mean(g_global, axis=0)
         err = float(jnp.max(jnp.abs(got[0] - want)))

@@ -307,6 +307,62 @@ class TestGridEquivalence:
             assert np.array_equal(getattr(ref, fld), getattr(pal, fld)), fld
 
 
+class TestRingOrder:
+    """The derived ring pops threads in the loops' FIFO order.
+
+    A one-op trace whose first suboperation is CPU makes the replay
+    draw-free (the random start offset and initial prefetch phase are
+    never read), and dyadic durations keep every sum exact, so threads
+    become ready at *identical* instants -- a wake-up at the very time a
+    runner re-enters the ring, simultaneous IO completions.  Any pop out
+    of the loops' order then shows in the cell's exact virtual time."""
+
+    U = 2.0 ** -26        # time unit: sums of multiples stay exact in f64
+    CASES = [dict(), dict(R_io=2.0 ** 26 / 64), dict(T_lock=4 * U),
+             dict(n_cores=2)]
+
+    @pytest.mark.parametrize("kw", CASES,
+                             ids=["plain", "iops", "lock", "cores2"])
+    def test_pop_order_matches_loop_exactly(self, kw):
+        U = self.U
+        trace = CompiledTrace.from_ops([Op((
+            (CPU, 16 * U), (MEM, 8 * U), (PREIO, 8 * U), (POSTIO, 8 * U),
+            (MEM, 8 * U)))])
+        cfg = SimConfig(P=2, seed=7, T_sw=4 * U, L_io=256 * U,
+                        L_io_jitter=0.0, **kw)
+        lats, cands = [64 * U, 512 * U], [1, 3, 8]
+        grid = sweep_grid(cfg, trace, lats, cands, n_ops=300)
+        for li, L in enumerate(lats):
+            for ci, n in enumerate(cands):
+                ref = simulate_compiled(
+                    dataclasses.replace(cfg, L_mem=L, n_threads=n),
+                    trace, 300)
+                assert grid.time[li, ci] == ref.time, (L / U, n)
+                assert grid.mem_stall_total[li, ci] == ref.mem_stall_total
+                assert grid.mem_accesses[li, ci] == ref.mem_accesses
+
+
+class TestBackendGuards:
+    """Off the CPU backend, the grid refuses what would silently leave the
+    accelerator (host-device sharding) or cannot compile there (the
+    float64 Pallas kernel) -- before anything is traced."""
+
+    @pytest.fixture
+    def on_tpu(self, monkeypatch):
+        monkeypatch.setattr(replay_jax.jax, "default_backend",
+                            lambda: "tpu")
+
+    def test_host_devices_raise_off_cpu(self, lsm_small, on_tpu):
+        with pytest.raises(ValueError, match="host CPU devices"):
+            sweep_grid(SimConfig(), lsm_small.trace, [1 * US], [8],
+                       host_devices=2)
+
+    def test_pallas_raises_off_cpu(self, lsm_small, on_tpu):
+        with pytest.raises(ValueError, match="Queue 1 item 3"):
+            sweep_grid(SimConfig(), lsm_small.trace, [1 * US], [8],
+                       use_pallas=True)
+
+
 # -- 3b. cohorts, early exit, host sharding ----------------------------------
 
 
@@ -429,10 +485,6 @@ class TestCohortEarlyExit:
 
 class TestValidation:
     def test_rejects_multicore_mixtures_and_empty(self, lsm_small):
-        # Multi-core fits as long as n_cores * T_max fits the tag bits.
-        with pytest.raises(ValueError, match="tag"):
-            sweep_grid(SimConfig(n_cores=4), lsm_small.trace, [1 * US],
-                       [128])
         with pytest.raises(ValueError, match="n_cores"):
             sweep_grid(SimConfig(n_cores=0), lsm_small.trace, [1 * US], [8])
         with pytest.raises(ValueError, match="scalar latencies"):
@@ -487,6 +539,40 @@ class TestSweepIntegration:
         assert jb.result.throughput != lb.result.throughput   # jax-run cell
         assert abs(jb.result.throughput - lb.result.throughput) \
             / lb.result.throughput < 0.02
+
+    def test_loop_workers_never_import_jax(self):
+        """Mixture cells on backend="jax" run in loop workers forked from
+        a forkserver that preloads ``repro.core.sim.sweep``.  Neither that
+        preload nor a worker's task may import jax: on an accelerator
+        machine a worker that initialized a backend would contend for the
+        chip with its parent."""
+        import os
+        import subprocess
+        import sys
+        import textwrap
+
+        prog = textwrap.dedent("""
+            import pickle, sys
+            import repro.core.sim.sweep as sweep
+            from repro.core import workloads
+            from repro.core.engines import LSMStore, run_trace
+            from repro.core.sim import SimConfig
+            tr = pickle.loads(pickle.dumps(run_trace(
+                LSMStore(2_000),
+                workloads.zipf(2_000, 600, 0.99, (1, 0), seed=3)).trace))
+            sweep._worker_init(tr, None, 200, None, False)
+            sweep._worker_run(SimConfig(P=12, seed=7, n_threads=8,
+                                        L_mem=[(5e-6, 0.9), (14e-6, 0.1)]))
+            assert "jax" not in sys.modules, "a loop worker imported jax"
+            print("NO_JAX")
+        """)
+        src = os.path.join(os.path.dirname(__file__), os.pardir, "src")
+        env = dict(os.environ, PYTHONPATH=os.pathsep.join(
+            filter(None, [src, os.environ.get("PYTHONPATH", "")])))
+        out = subprocess.run([sys.executable, "-c", prog], env=env,
+                             capture_output=True, text=True, timeout=300)
+        assert out.returncode == 0, out.stderr[-2000:]
+        assert "NO_JAX" in out.stdout
 
     def test_experiment_runs_with_jax_backend(self):
         sc = default_scenario("hash-index", n_keys=8_000, n_wl_ops=3_000,

@@ -1,0 +1,138 @@
+"""The jax sweep grid compiles for a TPU v5e chip that is described, not
+attached.
+
+Each case drives one ``chip_smoke.py`` phase through
+``Experiment.run(backend="jax")`` on the CPU with the grid program stubbed
+out, records the arguments of its widest cohort, and compiles
+``replay_jax._grid_body`` for one described v5e chip at exactly those
+shapes.  What the TPU compiler refuses fails here instead of on the chip.
+Every case also asserts that the lowered program holds no 64-bit
+``bitcast_convert``: XLA:TPU emulates float64 and cannot rewrite one.
+
+The topology is described inside a fixture, never at import time, so every
+pytest-xdist worker collects the same tests and only the worker running
+this file loads the TPU compiler.
+"""
+import dataclasses
+import importlib.util
+import re
+from pathlib import Path
+
+import numpy as np
+import pytest
+
+jax = pytest.importorskip("jax")
+
+from jax.sharding import SingleDeviceSharding  # noqa: E402
+
+from repro.core.experiment import Experiment, RunOptions  # noqa: E402
+from repro.core.sim import replay_jax  # noqa: E402
+from repro.core.sim.arrivals import HIST_BINS  # noqa: E402
+
+ROOT = Path(__file__).resolve().parents[1]
+
+
+def _chip_smoke():
+    spec = importlib.util.spec_from_file_location(
+        "chip_smoke", ROOT / "chip_smoke.py")
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+def _phase(label):
+    """The chip_smoke scenario with this label, full size."""
+    for _, name, sc in _chip_smoke()._scenarios(tiny=False):
+        if name == label:
+            return sc
+    raise KeyError(label)
+
+
+def _open_loop():
+    # The smoke test derives its rate from a closed-loop run; the rate
+    # does not change any shape, so a fixed one stands in for it here.
+    return dataclasses.replace(_phase("lsm_open_loop"), arrival={
+        "kind": "poisson", "rate": 100e3, "seed": 11, "deadline": 1e-3})
+
+
+CASES = {
+    "closed_1ssd": lambda: _phase("lsm"),
+    "io_clocks_2ssd": lambda: _phase("hash_index_2ssd"),
+    "open_loop_pct_deadline": _open_loop,
+    "cores4": lambda: _phase("lsm_4core"),
+}
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 -- no TPU compiler here
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture
+def no_persistent_cache():
+    """Compiles for a described chip are written to the persistent cache
+    but cannot be read back without one; keep them out of it."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    before = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    try:
+        yield
+    finally:
+        jax.config.update("jax_enable_compilation_cache", before)
+        cc.reset_cache()
+
+
+def _record_grid_calls(monkeypatch, sc):
+    """Run ``sc`` on the jax backend with the grid program replaced by a
+    recorder; returns ``[(args, static), ...]``, one per cohort."""
+    calls = []
+
+    def record(*args, **static):
+        calls.append((args, static))
+        G = args[5].shape[0]
+        n_ops = float(args[8])
+        out = dict(
+            throughput=np.full(G, 1e5), time=np.ones(G),
+            mem_stall_total=np.zeros(G), mem_accesses=np.zeros(G, np.int64),
+            counted=np.full(G, n_ops), steps_run=np.zeros(G, np.int64))
+        if static["has_lat"]:
+            hist = np.zeros((G, HIST_BINS))
+            hist[:, HIST_BINS // 2] = n_ops
+            out.update(lat_hist=hist, lat_max=np.full(G, 1e-4),
+                       missed=np.zeros(G, np.int32))
+        return out
+
+    monkeypatch.setattr(replay_jax, "_run_grid", record)
+    Experiment(sc, RunOptions(backend="jax",
+                              collect_percentiles=bool(sc.arrival))).run()
+    return calls
+
+
+_BITCAST64 = re.compile(r"bitcast_convert.*(f64|i64|ui64)")
+
+
+@pytest.mark.parametrize("case", sorted(CASES))
+def test_grid_compiles_for_v5e(case, one_chip, no_persistent_cache,
+                               monkeypatch):
+    run_grid = replay_jax._run_grid
+    calls = _record_grid_calls(monkeypatch, CASES[case]())
+    assert calls, "the jax backend never reached the grid program"
+    args, static = max(calls, key=lambda c: c[1]["T_max"])
+    shapes = jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(np.shape(a), a.dtype,
+                                       sharding=one_chip), args)
+    with jax.enable_x64(True), jax.threefry_partitionable(False):
+        lowered = run_grid.lower(*shapes, **static)
+    hlo = lowered.as_text()
+    assert not _BITCAST64.findall(hlo), "64-bit bitcast in the grid program"
+    compiled = lowered.compile()
+    assert compiled.memory_analysis() is not None
