@@ -510,6 +510,7 @@ def sweep_cluster(
     unroll: int | None = None,
     substeps: int | None = None,
     host_devices: int | None = None,
+    grid_records: list | None = None,
 ) -> list[ClusterPoint]:
     """Throughput vs. memory latency for a sharded fleet.
 
@@ -522,7 +523,9 @@ def sweep_cluster(
     ties).  ``backend`` selects how node cells execute: the compiled loop
     (``"loop"``), the generic event loop (``"generic"``, the equivalence
     harness), or the jax grid (``"jax"``; mixture latencies fall back to
-    the compiled loop per cell, like the plain sweep).
+    the compiled loop per cell, like the plain sweep).  ``grid_records``,
+    when given, receives each node's jax grid
+    :class:`~repro.core.sim.replay_jax.GridRecord`, in node order.
 
     Returns one :class:`ClusterPoint` per latency: ``result`` aggregates
     the fleet (throughput summed, makespan time, fleet-merged tail
@@ -597,6 +600,8 @@ def sweep_cluster(
                     arrivals=plan.node_arrivals[k],
                     collect_percentiles=collect_percentiles,
                     deadline=plan.node_deadline, **jax_opts)
+                if grid_records is not None:
+                    grid_records.append(grid.record)
                 row_of = {li: r for r, li in enumerate(scalar_lis)}
             grids[k] = (grid, row_of)
             cells[k] = [

@@ -320,9 +320,12 @@ class SweepRow:
 class RunArtifact:
     """Everything one experiment run produced, as serializable data.
 
-    ``points`` / ``trace_result`` are live in-process handles (the raw
-    :class:`SweepPoint` list and :class:`TraceResult`) populated by
-    :meth:`Experiment.run`; they are excluded from equality and JSON, so
+    ``points`` / ``trace_result`` / ``grid_records`` are live in-process
+    handles (the raw :class:`SweepPoint` list, the :class:`TraceResult`,
+    and one :class:`~repro.core.sim.replay_jax.GridRecord` per jax grid
+    call of the run -- one per cluster node -- with its host phases and
+    per-cohort scan steps) populated by :meth:`Experiment.run`; they are
+    excluded from equality and JSON, so
     ``RunArtifact.from_json(a.to_json()) == a`` holds.
     """
 
@@ -340,6 +343,7 @@ class RunArtifact:
     points: list = field(default=None, compare=False, repr=False)
     trace_result: TraceResult | None = field(
         default=None, compare=False, repr=False)
+    grid_records: tuple = field(default=(), compare=False, repr=False)
 
     def __post_init__(self):
         self.rows = tuple(
@@ -474,6 +478,7 @@ class Experiment:
         cfg = s.sim_config()
         arrival = s.arrival_spec()
         cl = s.cluster_spec()
+        grids: list = []
         if cl is not None:
             from .cluster import sweep_cluster
             # Trace ops carry no keys; the partitioner needs them, and the
@@ -486,6 +491,7 @@ class Experiment:
                 collect_percentiles=o.collect_percentiles, arrival=arrival,
                 use_pallas=o.use_pallas, unroll=o.unroll,
                 substeps=o.substeps, host_devices=o.host_devices,
+                grid_records=grids,
             )
         else:
             pts = sweep_latency(
@@ -495,6 +501,7 @@ class Experiment:
                 backend=o.backend, use_pallas=o.use_pallas, unroll=o.unroll,
                 substeps=o.substeps, host_devices=o.host_devices,
                 arrival=arrival, collect_percentiles=o.collect_percentiles,
+                grid_records=grids,
             )
         # Eq. 14 outer IO caps for the model column, matching the scenario's
         # declared device pool (aggregate over the n_ssd per-device rates;
@@ -539,6 +546,7 @@ class Experiment:
             rows=rows,
             points=pts,
             trace_result=tr,
+            grid_records=tuple(grids),
         )
 
 

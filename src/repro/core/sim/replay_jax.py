@@ -72,6 +72,7 @@ from __future__ import annotations
 import numbers
 import os
 import struct
+import time
 import zlib
 from dataclasses import dataclass
 from functools import lru_cache, partial
@@ -101,7 +102,8 @@ from ..trace_ir import CPU, CompiledTrace
 from .arrivals import HIST_BINS, LatencySummary, hist_bin_value
 from .config import SimConfig, SimResult
 
-__all__ = ["TraceArrays", "GridResult", "sweep_grid", "lower_trace"]
+__all__ = ["TraceArrays", "GridResult", "GridRecord", "CohortRecord",
+           "sweep_grid", "lower_trace"]
 
 _STEP_BUCKET = 4096     # scan lengths round up to this (compile-cache reuse)
 _PAD_SENTINEL = CPU     # padded suboperations are inert plain-CPU entries
@@ -171,6 +173,78 @@ def lower_trace(trace: CompiledTrace, bucket: int = 1024) -> TraceArrays:
 
 
 @dataclass(frozen=True)
+class CohortRecord:
+    """One cohort's compiled call, as :func:`sweep_grid` ran it.
+
+    ``cells`` grid cells share a ``T_max``-wide thread plane and a scan
+    compiled for ``steps_bound`` steps; ``steps_run`` is what the chunk
+    loop executed before its early exit (the longest shard's under
+    ``host_devices``), ``cell_steps_run`` its sum over the cohort's cells.
+    The ``t_*`` fields are host-clock (:func:`time.perf_counter_ns`) edges
+    of the cohort's three phases, each also a profiler span of the same
+    name carrying ``cells``, ``T_max`` and ``steps_bound``:
+    ``grid_dispatch`` (the call of the jitted program, to its return; on a
+    first call it holds tracing, lowering and the compile or cache load),
+    ``grid_wait`` (the host waiting on the device for the outputs)
+    and ``grid_reduce`` (the copy to the host and the host reduction).
+    """
+
+    cells: int
+    T_max: int
+    steps_bound: int
+    steps_run: int
+    cell_steps_run: int
+    t_dispatch: int
+    t_wait: int
+    t_reduce: int
+    t_done: int
+
+    @property
+    def dispatch_ns(self) -> int:
+        return self.t_wait - self.t_dispatch
+
+    @property
+    def wait_ns(self) -> int:
+        return self.t_reduce - self.t_wait
+
+    @property
+    def reduce_ns(self) -> int:
+        return self.t_done - self.t_reduce
+
+
+@dataclass(frozen=True)
+class GridRecord:
+    """What one :func:`sweep_grid` call did: the host-clock edges of its
+    ``grid_lower`` phase (lowering the trace, partitioning the cohorts,
+    uploading the arrival array; a profiler span too) and one
+    :class:`CohortRecord` per cohort, in run order.  The call's step
+    counters derive from the cohort records."""
+
+    t_lower: int
+    t_lowered: int
+    cohorts: tuple[CohortRecord, ...]
+
+    @property
+    def lower_ns(self) -> int:
+        return self.t_lowered - self.t_lower
+
+    @property
+    def steps(self) -> int:
+        """Scan length bound (max across cohorts)."""
+        return max((c.steps_bound for c in self.cohorts), default=0)
+
+    @property
+    def cell_steps_bound(self) -> int:
+        """Sum over cells of their cohort's bound."""
+        return sum(c.steps_bound * c.cells for c in self.cohorts)
+
+    @property
+    def cell_steps_run(self) -> int:
+        """Sum over cells of executed steps."""
+        return sum(c.cell_steps_run for c in self.cohorts)
+
+
+@dataclass(frozen=True)
 class GridResult:
     """Per-cell sweep results, shaped ``(n_latencies, n_candidates)``.
 
@@ -178,6 +252,7 @@ class GridResult:
     scan steps their cohort *scheduled* (the per-cohort worst-case bound)
     vs. actually *executed* before the cohort's early exit fired -- the
     difference is the wasted work the early-exit scan no longer pays.
+    They, and ``steps``, derive from ``record``'s per-cohort records.
     """
 
     throughput: np.ndarray
@@ -185,9 +260,7 @@ class GridResult:
     mem_stall_total: np.ndarray
     mem_accesses: np.ndarray
     ops: int                      # measured ops per cell (same for all)
-    steps: int                    # scan length bound (max across cohorts)
-    cell_steps_bound: int = 0     # sum over cells of their cohort's bound
-    cell_steps_run: int = 0      # sum over cells of executed steps
+    record: GridRecord            # the call's phases and cohort counters
     # Tail-latency planes, present only when ``collect_percentiles`` was
     # on: histogram-derived percentiles (source="hist"; each within
     # arrivals.HIST_REL_ERROR of the exact value), the exact max, the
@@ -202,6 +275,18 @@ class GridResult:
     # HIST_BINS) -- cluster sweeps sum these planes across nodes to build
     # fleet-wide percentile summaries without re-running cells.
     lat_hist: np.ndarray | None = None
+
+    @property
+    def steps(self) -> int:
+        return self.record.steps
+
+    @property
+    def cell_steps_bound(self) -> int:
+        return self.record.cell_steps_bound
+
+    @property
+    def cell_steps_run(self) -> int:
+        return self.record.cell_steps_run
 
     def result(self, li: int, ci: int) -> SimResult:
         """One cell as a :class:`SimResult` (no per-op latency columns --
@@ -628,12 +713,6 @@ def sweep_grid(
 
     from repro.kernels.sched_step import SPAN_SHIFT
 
-    source = trace if isinstance(trace, CompiledTrace) else trace.to_trace()
-    ta = trace if isinstance(trace, TraceArrays) else lower_trace(trace)
-    if int(ta.op_ends[-1]) >= (1 << SPAN_SHIFT):
-        raise ValueError(
-            f"trace has {int(ta.op_ends[-1])} suboperations; the fused "
-            f"step's span packing supports < 2**{SPAN_SHIFT}")
     n_lat, n_cand = len(latencies), len(candidates)
     backend = jax.default_backend()
     if use_pallas and backend != "cpu":
@@ -696,8 +775,6 @@ def sweep_grid(
         cfg.T_degrade,
         cfg.io_degrade,
     )
-    cohorts = _cohorts(source, candidates, n_ops, warmup_ops, cfg.n_cores,
-                       bucket_threads)
 
     shape = (n_lat, n_cand)
     thr = np.empty(shape)
@@ -712,15 +789,28 @@ def sweep_grid(
         lcount = np.empty(shape, dtype=np.int64)
         lmiss = np.empty(shape, dtype=np.int64)
         lhist = np.empty(shape + (HIST_BINS,), dtype=np.int64)
-    max_steps = 0
-    steps_bound_cells = 0
-    steps_run_cells = 0
+    records = []
+    span = jax.profiler.TraceAnnotation
     # The draws come from threefry in its non-partitionable bit layout: the
     # stream every tolerance contract, test bound and conformance-corpus
     # entry was measured on (JAX 0.5 made the partitionable layout the
     # default; per-cell draws never span devices, so its sharding benefit
     # does not apply here).
     with enable_x64(True), jax.threefry_partitionable(False):
+        t_lower = time.perf_counter_ns()
+        with span("grid_lower"):
+            source = (trace if isinstance(trace, CompiledTrace)
+                      else trace.to_trace())
+            ta = (trace if isinstance(trace, TraceArrays)
+                  else lower_trace(trace))
+            if int(ta.op_ends[-1]) >= (1 << SPAN_SHIFT):
+                raise ValueError(
+                    f"trace has {int(ta.op_ends[-1])} suboperations; the "
+                    f"fused step's span packing supports < 2**{SPAN_SHIFT}")
+            cohorts = _cohorts(source, candidates, n_ops, warmup_ops,
+                               cfg.n_cores, bucket_threads)
+            arr = jnp.asarray(arr_np)
+        t_lowered = time.perf_counter_ns()
         for cols, T_max, steps in cohorts:
             cand_b = [candidates[j] for j in cols]
             nc = len(cand_b)
@@ -730,7 +820,6 @@ def sweep_grid(
             warm_g = (np.full_like(nthr_g, warmup_ops)
                       if warmup_ops is not None
                       else 2 * nthr_g * cfg.n_cores)
-            max_steps = max(max_steps, steps)
 
             # Each cell's RNG stream is keyed by its (L_mem, n_threads)
             # VALUES, so a cell's result never depends on which other
@@ -757,62 +846,72 @@ def sweep_grid(
                 n_cores=cfg.n_cores, has_arr=has_arr, has_lat=has_lat,
                 has_deadline=has_deadline, **_make_flags(cfg),
             )
-            run = (_run_grid_sharded(n_dev, **static) if n_dev > 1
-                   else partial(_run_grid, **static))
-            out = run(
-                ta.kinds, ta.durs, ta.op_starts, ta.op_ends,
-                jnp.int32(ta.n_ops),
-                jnp.asarray(L_mem_g), jnp.asarray(nthr_g),
-                jnp.asarray(warm_g),
-                jnp.float64(n_ops),
-                tuple(jnp.float64(d) for d in dyn),
-                jax.random.PRNGKey(cfg.seed),
-                jnp.asarray(stream_ids),
-                jnp.asarray(arr_np),
-            )
-            out = {k: np.asarray(v)[:G] for k, v in out.items()}
-            if not np.all(out["counted"] >= n_ops):
-                short = int(out["counted"].min())
-                raise RuntimeError(
-                    f"jax replay under-ran its step bound ({steps} steps, "
-                    f"worst cell counted {short}/{n_ops} ops) -- this is "
-                    "a bug in _steps_bound")
-            steps_bound_cells += steps * G
-            steps_run_cells += int(out["steps_run"].sum())
-            bshape = (n_lat, nc)
-            thr[:, cols] = out["throughput"].reshape(bshape)
-            tim[:, cols] = out["time"].reshape(bshape)
-            stall[:, cols] = out["mem_stall_total"].reshape(bshape)
-            macc[:, cols] = out["mem_accesses"].reshape(bshape)
-            if has_lat:
-                # Host-side percentile reduction, vectorized over cells:
-                # nearest-rank on the cumulative counts, exactly
-                # arrivals.summarize_hist per row.
-                cum = np.cumsum(out["lat_hist"], axis=1)
-                total = np.rint(cum[:, -1]).astype(np.int64)
-                empty = total == 0
-                for q, dest in ((0.5, p50), (0.9, p90), (0.99, p99)):
-                    rank = np.ceil(q * np.maximum(total, 1))
-                    b = np.minimum((cum < rank[:, None]).sum(axis=1),
-                                   HIST_BINS - 1)
-                    dest[:, cols] = np.where(
-                        empty, np.nan, hist_bin_value(b)).reshape(bshape)
-                lmax[:, cols] = np.where(
-                    empty, np.nan, out["lat_max"]).reshape(bshape)
-                lcount[:, cols] = total.reshape(bshape)
-                lmiss[:, cols] = out["missed"].astype(
-                    np.int64).reshape(bshape)
-                lhist[:, cols, :] = np.rint(out["lat_hist"]).astype(
-                    np.int64).reshape(bshape + (HIST_BINS,))
+            args = dict(cells=G, T_max=T_max, steps_bound=steps)
+            t_dispatch = time.perf_counter_ns()
+            with span("grid_dispatch", **args):
+                run = (_run_grid_sharded(n_dev, **static) if n_dev > 1
+                       else partial(_run_grid, **static))
+                out = run(
+                    ta.kinds, ta.durs, ta.op_starts, ta.op_ends,
+                    jnp.int32(ta.n_ops),
+                    jnp.asarray(L_mem_g), jnp.asarray(nthr_g),
+                    jnp.asarray(warm_g),
+                    jnp.float64(n_ops),
+                    tuple(jnp.float64(d) for d in dyn),
+                    jax.random.PRNGKey(cfg.seed),
+                    jnp.asarray(stream_ids),
+                    arr,
+                )
+            t_wait = time.perf_counter_ns()
+            with span("grid_wait", **args):
+                jax.block_until_ready(out)
+            t_reduce = time.perf_counter_ns()
+            with span("grid_reduce", **args):
+                out = {k: np.asarray(v)[:G] for k, v in out.items()}
+                if not np.all(out["counted"] >= n_ops):
+                    short = int(out["counted"].min())
+                    raise RuntimeError(
+                        f"jax replay under-ran its step bound ({steps} "
+                        f"steps, worst cell counted {short}/{n_ops} ops) "
+                        "-- this is a bug in _steps_bound")
+                bshape = (n_lat, nc)
+                thr[:, cols] = out["throughput"].reshape(bshape)
+                tim[:, cols] = out["time"].reshape(bshape)
+                stall[:, cols] = out["mem_stall_total"].reshape(bshape)
+                macc[:, cols] = out["mem_accesses"].reshape(bshape)
+                if has_lat:
+                    # Host-side percentile reduction, vectorized over
+                    # cells: nearest-rank on the cumulative counts,
+                    # exactly arrivals.summarize_hist per row.
+                    cum = np.cumsum(out["lat_hist"], axis=1)
+                    total = np.rint(cum[:, -1]).astype(np.int64)
+                    empty = total == 0
+                    for q, dest in ((0.5, p50), (0.9, p90), (0.99, p99)):
+                        rank = np.ceil(q * np.maximum(total, 1))
+                        b = np.minimum((cum < rank[:, None]).sum(axis=1),
+                                       HIST_BINS - 1)
+                        dest[:, cols] = np.where(
+                            empty, np.nan, hist_bin_value(b)).reshape(bshape)
+                    lmax[:, cols] = np.where(
+                        empty, np.nan, out["lat_max"]).reshape(bshape)
+                    lcount[:, cols] = total.reshape(bshape)
+                    lmiss[:, cols] = out["missed"].astype(
+                        np.int64).reshape(bshape)
+                    lhist[:, cols, :] = np.rint(out["lat_hist"]).astype(
+                        np.int64).reshape(bshape + (HIST_BINS,))
+            records.append(CohortRecord(
+                cells=G, T_max=T_max, steps_bound=steps,
+                steps_run=int(out["steps_run"].max()),
+                cell_steps_run=int(out["steps_run"].sum()),
+                t_dispatch=t_dispatch, t_wait=t_wait, t_reduce=t_reduce,
+                t_done=time.perf_counter_ns()))
     return GridResult(
         throughput=thr,
         time=tim,
         mem_stall_total=stall,
         mem_accesses=macc,
         ops=n_ops,
-        steps=max_steps,
-        cell_steps_bound=steps_bound_cells,
-        cell_steps_run=steps_run_cells,
+        record=GridRecord(t_lower, t_lowered, tuple(records)),
         p50=p50 if has_lat else None,
         p90=p90 if has_lat else None,
         p99=p99 if has_lat else None,
@@ -821,3 +920,4 @@ def sweep_grid(
         missed=lmiss if has_lat else None,
         lat_hist=lhist if has_lat else None,
     )
+

@@ -138,12 +138,15 @@ def _pick_context(trace, src_fn):
 def _run_jax_cells(cfg: SimConfig, trace: CompiledTrace, latencies,
                    candidates, n_ops, warmup_ops, results, todo,
                    jax_opts=None, arrivals=None,
-                   collect_percentiles=False, deadline=0.0) -> None:
+                   collect_percentiles=False, deadline=0.0,
+                   grid_records=None) -> None:
     """Fill ``results[i]`` for every grid index in ``todo`` via the jax
     backend.  All missing scalar-latency cells run as one vectorized grid
-    call (:func:`repro.core.sim.replay_jax.sweep_grid`); mixture-latency
-    cells (which the jax backend does not model) run through the compiled
-    loop per cell.  ``jax_opts`` are extra ``sweep_grid`` tuning kwargs
+    call (:func:`repro.core.sim.replay_jax.sweep_grid`), whose
+    :class:`~repro.core.sim.replay_jax.GridRecord` is appended to
+    ``grid_records`` when given; mixture-latency cells (which the jax
+    backend does not model) run through the compiled loop per cell.
+    ``jax_opts`` are extra ``sweep_grid`` tuning kwargs
     (``use_pallas``/``unroll``/``substeps``) -- they select execution
     strategy, never values."""
     from . import replay_jax   # deferred: jax is a heavyweight import
@@ -162,6 +165,8 @@ def _run_jax_cells(cfg: SimConfig, trace: CompiledTrace, latencies,
             n_ops, warmup_ops, arrivals=arrivals,
             collect_percentiles=collect_percentiles, deadline=deadline,
             **(jax_opts or {}))
+        if grid_records is not None:
+            grid_records.append(grid.record)
     row_of = {li: r for r, li in enumerate(need_lis)}
     for i in todo:
         li, ci = divmod(i, k)
@@ -412,6 +417,7 @@ def sweep_latency(
     host_devices: int | None = None,
     arrival: ArrivalSpec | dict | None = None,
     collect_percentiles: bool = False,
+    grid_records: list | None = None,
 ) -> list[SweepPoint]:
     """Throughput vs. memory latency with per-point thread optimization.
 
@@ -504,6 +510,11 @@ def sweep_latency(
         exact nearest-rank on the loop backends, log-histogram on the jax
         backend (within ``arrivals.HIST_REL_ERROR``).  Cache-friendly,
         unlike ``collect_latency``.
+    grid_records
+        A list that receives the
+        :class:`~repro.core.sim.replay_jax.GridRecord` of the jax grid
+        call (its host phases and per-cohort scan steps); untouched when no
+        grid runs (loop backend, or every cell cached).
 
     Returns one :class:`SweepPoint` per latency, in input order.
     """
@@ -598,7 +609,8 @@ def sweep_latency(
             jax_opts["host_devices"] = host_devices
         _run_jax_cells(cfg, trace, latencies, candidates, n_ops,
                        warmup_ops, results, todo, jax_opts,
-                       arrivals_arr, collect_percentiles, deadline)
+                       arrivals_arr, collect_percentiles, deadline,
+                       grid_records)
         if use_cache:
             for i in todo:
                 _cache_store(paths[i], results[i])

@@ -1,5 +1,6 @@
 """The unified experiment API: workload registry, scenario specs,
 run artifacts, CLI discovery flags, and matrix-sweep equivalence."""
+import dataclasses
 import json
 from pathlib import Path
 
@@ -245,6 +246,31 @@ class TestRunArtifact:
         assert art.rows[1].mean_latency_us == pytest.approx(5.934)
         assert "Lmix" in art.rows[1].label()
         assert RunArtifact.from_json(art.to_json()) == art
+
+    def test_loop_run_has_no_grid_records(self, artifact):
+        assert artifact.grid_records == ()
+
+    @pytest.mark.parametrize("cluster", [None, {"n_nodes": 2}])
+    def test_jax_run_returns_grid_records(self, cluster):
+        """Experiment.run() hands over the record of every grid call of
+        the run (one per fleet node), outside equality and JSON."""
+        spec = dict(SMALL)
+        if cluster:
+            spec["cluster"] = cluster
+        sc = default_scenario("hash-index", n_ssd=2, **spec)
+        art = run_scenario(sc, RunOptions(backend="jax"))
+        n_cells = len(sc.latencies_us) * len(sc.thread_candidates)
+        assert len(art.grid_records) == (cluster or {}).get("n_nodes", 1)
+        for rec in art.grid_records:
+            assert sum(c.cells for c in rec.cohorts) == n_cells
+            assert 0 < rec.cell_steps_run <= rec.cell_steps_bound
+        assert set(art.to_dict()) == {
+            "schema_version", "scenario", "engine", "workload", "S", "M",
+            "T_mem_us", "T_io_pre_us", "T_io_post_us", "hit_stats", "rows"}
+        assert (art.to_dict()
+                == dataclasses.replace(art, grid_records=()).to_dict())
+        again = RunArtifact.from_json(art.to_json())
+        assert again == art and again.grid_records == ()
 
     def test_run_options_cache_dir(self, tmp_path):
         sc = default_scenario("hash-index", n_ssd=2, **SMALL)

@@ -480,6 +480,99 @@ class TestCohortEarlyExit:
         assert "SHARDED_OK" in out.stdout
 
 
+class TestCohortRecords:
+    """Each sweep_grid call keeps one record per cohort: its shape, the
+    scan steps it ran, and the host clock at the edges of its phases; the
+    grid's step counters derive from those records."""
+
+    @pytest.mark.parametrize("n_ops, cohorts_counts, monolithic_counts", [
+        (300, (8192, 32768, 26624), (8192, 49152, 49152)),
+        (500, (8192, 49152, 38912), (8192, 49152, 49152)),
+    ])
+    def test_derived_counters_keep_their_values(self, lsm_small, n_ops,
+                                                cohorts_counts,
+                                                monolithic_counts):
+        """``(steps, cell_steps_bound, cell_steps_run)`` read what the
+        three accumulators gave before they derived from the records, on
+        _het_grids' cells in both layouts."""
+        grids = _het_grids(lsm_small.trace, SimConfig(P=12, seed=7),
+                           n_ops=n_ops)
+        for g, counts in zip(grids, (cohorts_counts, monolithic_counts)):
+            assert (g.steps, g.cell_steps_bound, g.cell_steps_run) == counts
+            cohorts = g.record.cohorts
+            assert g.steps == max(c.steps_bound for c in cohorts)
+            assert g.cell_steps_bound == sum(c.steps_bound * c.cells
+                                             for c in cohorts)
+            assert g.cell_steps_run == sum(c.cell_steps_run
+                                           for c in cohorts)
+
+    def test_records_cover_the_grid_in_run_order(self, lsm_small):
+        coh, mono = _het_grids(lsm_small.trace, SimConfig(P=12, seed=7))
+        assert len(mono.record.cohorts) == 1
+        assert len(coh.record.cohorts) > 1
+        for g in (coh, mono):
+            rec = g.record
+            assert sum(c.cells for c in rec.cohorts) == g.throughput.size
+            t = rec.t_lower
+            assert rec.t_lowered >= t and rec.lower_ns >= 0
+            t = rec.t_lowered
+            for c in rec.cohorts:
+                edges = (c.t_dispatch, c.t_wait, c.t_reduce, c.t_done)
+                assert t <= edges[0] and list(edges) == sorted(edges)
+                assert (c.dispatch_ns + c.wait_ns + c.reduce_ns
+                        == c.t_done - c.t_dispatch)
+                t = c.t_done
+
+    def test_steps_run_whole_chunks_within_bound(self, lsm_small):
+        coh, mono = _het_grids(lsm_small.trace, SimConfig(P=12, seed=7))
+        for c in coh.record.cohorts + mono.record.cohorts:
+            assert c.steps_run % replay_jax._RNG_CHUNK == 0
+            assert 0 < c.steps_run <= c.steps_bound
+            # one device: every cell of a cohort runs the same steps
+            assert c.cell_steps_run == c.steps_run * c.cells
+        # early_exit=False scans the monolithic cohort to its bound
+        (m,) = mono.record.cohorts
+        assert m.steps_run == m.steps_bound
+
+    def test_profiler_trace_holds_the_spans(self, lsm_small, tmp_path):
+        """The spans land in the profiler's trace: one grid_lower per
+        call, one grid_dispatch / grid_wait / grid_reduce per cohort, each
+        carrying its cohort's cells, T_max and steps_bound."""
+        import glob
+        import os
+
+        from jax.profiler import ProfileData
+
+        cfg = SimConfig(P=12, seed=7)
+        opts = jax.profiler.ProfileOptions()
+        opts.python_tracer_level = 0
+        jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+        try:
+            g = sweep_grid(cfg, lsm_small.trace, [0.5 * US, 5 * US],
+                           [4, 8, 24], n_ops=300)
+        finally:
+            jax.profiler.stop_trace()
+        (path,) = glob.glob(os.path.join(str(tmp_path), "plugins",
+                                         "profile", "*", "*.xplane.pb"))
+        names = ("grid_lower", "grid_dispatch", "grid_wait", "grid_reduce")
+        found = {n: [] for n in names}
+        for plane in ProfileData.from_file(path).planes:
+            if not plane.name.startswith("/host:"):
+                continue
+            for line in plane.lines:
+                for e in line.events:
+                    if e.name in found:
+                        found[e.name].append(
+                            (e.start_ns, dict(e.stats)))
+        cohorts = g.record.cohorts
+        assert len(found["grid_lower"]) == 1
+        want = [(c.cells, c.T_max, c.steps_bound) for c in cohorts]
+        for n in names[1:]:
+            got = [(a["cells"], a["T_max"], a["steps_bound"])
+                   for _, a in sorted(found[n], key=lambda x: x[0])]
+            assert got == want, n
+
+
 # -- 4. validation and API contracts -----------------------------------------
 
 
