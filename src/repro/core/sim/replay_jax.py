@@ -33,9 +33,10 @@ Cells that complete their measured ops latch their measurement (the
 counters stop; the simulation harmlessly idles on) while the scan drains
 the slower cells; the scan length is a worst-case bound computed from the
 trace's op-length prefix sums, so no cell can run out of steps.  Grids
-whose thread candidates span a wide range are split into power-of-two
-thread *buckets* so small-thread cells do not pay the widest cell's
-``T_max`` padding (per-cell RNG purity makes the split invisible to
+whose thread candidates span a wide range are split into thread
+*buckets* so small-thread cells do not pay the widest cell's ``T_max``
+padding where padding costs: power-of-two buckets on the CPU, 128-lane
+tiles on the TPU (per-cell RNG purity makes the split invisible to
 results).
 
 Exactness
@@ -106,6 +107,12 @@ __all__ = ["TraceArrays", "GridResult", "GridRecord", "CohortRecord",
            "sweep_grid", "lower_trace"]
 
 _STEP_BUCKET = 4096     # scan lengths round up to this (compile-cache reuse)
+_LANES = 128            # lanes of a TPU vector register (thread-slot axis)
+# Lane-tiled cohorts stop merging at this many cells.  On one TPU v5e a
+# 128-cell step costs ~78 us, a 12-cell step ~27 us: little of the 128-cell
+# step is fixed cost, and one 384-cell step (262 us) cost more than three
+# 128-cell steps (235 us together).
+_MERGE_CELLS = 128
 _PAD_SENTINEL = CPU     # padded suboperations are inert plain-CPU entries
 
 
@@ -176,9 +183,11 @@ def lower_trace(trace: CompiledTrace, bucket: int = 1024) -> TraceArrays:
 class CohortRecord:
     """One cohort's compiled call, as :func:`sweep_grid` ran it.
 
-    ``cells`` grid cells share a ``T_max``-wide thread plane and a scan
-    compiled for ``steps_bound`` steps; ``steps_run`` is what the chunk
-    loop executed before its early exit (the longest shard's under
+    ``cells`` grid cells share a ``T_max``-wide thread plane (per core)
+    and a scan compiled for ``steps_bound`` steps; ``thread_slots`` is
+    the slots the cells use, the sum of ``n_cores * n_threads`` over
+    them (the rest of the plane is padding).  ``steps_run`` is what the
+    chunk loop executed before its early exit (the longest shard's under
     ``host_devices``), ``cell_steps_run`` its sum over the cohort's cells.
     The ``t_*`` fields are host-clock (:func:`time.perf_counter_ns`) edges
     of the cohort's three phases, each also a profiler span of the same
@@ -191,6 +200,7 @@ class CohortRecord:
 
     cells: int
     T_max: int
+    thread_slots: int
     steps_bound: int
     steps_run: int
     cell_steps_run: int
@@ -217,11 +227,13 @@ class GridRecord:
     """What one :func:`sweep_grid` call did: the host-clock edges of its
     ``grid_lower`` phase (lowering the trace, partitioning the cohorts,
     uploading the arrival array; a profiler span too) and one
-    :class:`CohortRecord` per cohort, in run order.  The call's step
-    counters derive from the cohort records."""
+    :class:`CohortRecord` per cohort, in run order, whose planes are
+    ``n_cores * T_max`` slots wide.  The call's step counters and
+    ``slot_fill`` derive from the cohort records."""
 
     t_lower: int
     t_lowered: int
+    n_cores: int
     cohorts: tuple[CohortRecord, ...]
 
     @property
@@ -242,6 +254,14 @@ class GridRecord:
     def cell_steps_run(self) -> int:
         """Sum over cells of executed steps."""
         return sum(c.cell_steps_run for c in self.cohorts)
+
+    @property
+    def slot_fill(self) -> float:
+        """Share of the cohorts' thread planes that cells use: the sum of
+        ``thread_slots`` over the sum of ``cells * T_max * n_cores``; the
+        rest is padding that merging narrow cells into wide planes costs."""
+        planes = sum(c.cells * c.T_max for c in self.cohorts) * self.n_cores
+        return sum(c.thread_slots for c in self.cohorts) / planes
 
 
 @dataclass(frozen=True)
@@ -587,31 +607,44 @@ def _run_grid_sharded(n_dev: int, **static):
     return jax.jit(fn)
 
 
-def _thread_buckets(candidates: Sequence[int]) -> list[list[int]]:
-    """Group candidate indices by the power-of-two ceiling of their thread
-    count, so narrow cells never pay a wide cell's ``T_max`` padding (a
-    16-thread cell in a 128-wide plane does 8x the per-step plane work it
-    needs).  Cells are RNG-pure per (L_mem, n_threads), so bucketing
-    cannot change any cell's result."""
-    groups: dict[int, list[int]] = {}
-    for j, c in enumerate(candidates):
-        b = 1 if c <= 1 else 1 << (c - 1).bit_length()
-        groups.setdefault(b, []).append(j)
-    return [ix for _, ix in sorted(groups.items())]
+def _lane_tiled() -> bool:
+    """Whether a plane's per-step cost follows its 128-lane tiles rather
+    than its element count: true on the TPU, where the thread-slot axis is
+    the vector lanes and a scan step pays a fixed cost for its string of
+    small ops, whatever the plane's width up to one tile."""
+    return jax.default_backend() == "tpu"
 
 
-def _cohorts(source: CompiledTrace, candidates: Sequence[int], n_ops: int,
-             warmup_ops: int | None, n_cores: int,
+def _thread_bucket(n_threads: int, n_cores: int) -> int:
+    """The thread bucket of a candidate; cells of one bucket may share a
+    thread plane.  On the CPU plane work scales with elements, so the
+    bucket is the power-of-two ceiling of ``n_threads`` (a 16-thread cell
+    in a 128-wide plane would do 8x the work it needs).  Lane-tiled (see
+    :func:`_lane_tiled`), it is the number of 128-lane tiles the cell's
+    ``n_cores * n_threads`` slots fill: padding inside a tile is free,
+    and each cohort pays the per-step fixed cost once more."""
+    if _lane_tiled():
+        return -(-n_cores * n_threads // _LANES)
+    return 1 if n_threads <= 1 else 1 << (n_threads - 1).bit_length()
+
+
+def _cohorts(source: CompiledTrace, candidates: Sequence[int], n_lat: int,
+             n_ops: int, warmup_ops: int | None, n_cores: int,
              bucket_threads: bool) -> list[tuple[list[int], int, int]]:
-    """Partition candidate columns into scan cohorts: ``(cols, T_max,
-    steps)`` groups sharing a thread bucket *and* a step bound.
+    """Partition candidate columns (``n_lat`` cells each) into scan
+    cohorts: ``(cols, T_max, steps)`` groups sharing a thread bucket *and*
+    a step bound.
 
-    The thread buckets are :func:`_thread_buckets`'s power-of-two ceilings;
-    within a bucket, candidates whose per-cell worst-case bound lands in a
-    different ``_STEP_BUCKET`` split into their own cohort, so a cohort's
-    early exit is never held open by a cell with a structurally larger
-    bound (uneven warmups are the common case: warmup defaults to
-    ``2 * threads * cores``).  Per-cell RNG purity makes any partition
+    The thread buckets are :func:`_thread_bucket`'s, which the platform
+    decides; within a bucket, candidates whose per-cell worst-case bound
+    lands in a different ``_STEP_BUCKET`` split into their own cohort, so
+    a cohort's early exit is never held open by a cell with a structurally
+    larger bound (uneven warmups are the common case: warmup defaults to
+    ``2 * threads * cores``).  Lane-tiled, a group's columns merge,
+    narrowest first, only while the cohort holds fewer than
+    ``_MERGE_CELLS`` cells: past that a step's fixed cost is small beside
+    its per-cell cost, and a wider cohort costs more than the ones it
+    would replace.  Per-cell RNG purity makes any partition
     result-invariant; ``bucket_threads=False`` collapses everything into
     the single monolithic scan (one ``T_max``, one global bound)."""
     if not bucket_threads:
@@ -622,12 +655,22 @@ def _cohorts(source: CompiledTrace, candidates: Sequence[int], n_ops: int,
         return [(list(range(len(candidates))), T_max, steps)]
     groups: dict[tuple[int, int], list[int]] = {}
     for j, c in enumerate(candidates):
-        b = 1 if c <= 1 else 1 << (c - 1).bit_length()
+        b = _thread_bucket(c, n_cores)
         warm = warmup_ops if warmup_ops is not None else 2 * c * n_cores
         steps = _steps_bound(source, n_ops, warm, c * n_cores)
         groups.setdefault((b, steps), []).append(j)
-    return [(ix, max(candidates[j] for j in ix), steps)
-            for (_, steps), ix in sorted(groups.items())]
+    cohorts = []
+    for (_, steps), ix in sorted(groups.items()):
+        parts = [ix]
+        if _lane_tiled():
+            parts = [[]]
+            for j in sorted(ix, key=lambda j: candidates[j]):
+                if len(parts[-1]) * n_lat >= _MERGE_CELLS:
+                    parts.append([])
+                parts[-1].append(j)
+        cohorts += [(cols, max(candidates[j] for j in cols), steps)
+                    for cols in parts]
+    return cohorts
 
 
 def sweep_grid(
@@ -662,6 +705,9 @@ def sweep_grid(
     backend only (interpreted), and raises elsewhere because the kernel
     does not compile for TPU yet.  The default jnp scan path uses
     ``unroll`` to amortize dispatch instead.
+    ``bucket_threads=True`` groups the candidates into cohorts by thread
+    bucket (power-of-two on the CPU, 128-lane tiles on the TPU; see
+    :func:`_thread_bucket`) and step bound;
     ``bucket_threads=False`` forces the single monolithic layout (all
     candidates padded to one ``T_max``, one global step bound);
     ``early_exit=False`` additionally scans every cohort to its full
@@ -807,8 +853,8 @@ def sweep_grid(
                 raise ValueError(
                     f"trace has {int(ta.op_ends[-1])} suboperations; the "
                     f"fused step's span packing supports < 2**{SPAN_SHIFT}")
-            cohorts = _cohorts(source, candidates, n_ops, warmup_ops,
-                               cfg.n_cores, bucket_threads)
+            cohorts = _cohorts(source, candidates, n_lat, n_ops,
+                               warmup_ops, cfg.n_cores, bucket_threads)
             arr = jnp.asarray(arr_np)
         t_lowered = time.perf_counter_ns()
         for cols, T_max, steps in cohorts:
@@ -900,7 +946,9 @@ def sweep_grid(
                     lhist[:, cols, :] = np.rint(out["lat_hist"]).astype(
                         np.int64).reshape(bshape + (HIST_BINS,))
             records.append(CohortRecord(
-                cells=G, T_max=T_max, steps_bound=steps,
+                cells=G, T_max=T_max,
+                thread_slots=n_lat * cfg.n_cores * sum(cand_b),
+                steps_bound=steps,
                 steps_run=int(out["steps_run"].max()),
                 cell_steps_run=int(out["steps_run"].sum()),
                 t_dispatch=t_dispatch, t_wait=t_wait, t_reduce=t_reduce,
@@ -911,7 +959,7 @@ def sweep_grid(
         mem_stall_total=stall,
         mem_accesses=macc,
         ops=n_ops,
-        record=GridRecord(t_lower, t_lowered, tuple(records)),
+        record=GridRecord(t_lower, t_lowered, cfg.n_cores, tuple(records)),
         p50=p50 if has_lat else None,
         p90=p90 if has_lat else None,
         p99=p99 if has_lat else None,

@@ -420,6 +420,67 @@ class TestCohortEarlyExit:
         # early_exit=False runs every scheduled step
         assert mono.cell_steps_run == mono.cell_steps_bound
 
+    @pytest.mark.parametrize("backend, cands, n_lat, n_cores, n_ops, want", [
+        # one 128-lane tile holds every candidate of the paper's grid
+        ("tpu", [16, 24, 32, 48, 64], 6, 1, 100, [([0, 1, 2, 3, 4], 64)]),
+        # past 128 slots a second tile starts a second cohort
+        ("tpu", [8, 16, 32, 64, 128, 256], 2, 1, 100,
+         [([0, 1, 2, 3, 4], 128), ([5], 256)]),
+        # slots count every core: 96 and 128 slots share one tile
+        ("tpu", [48, 64], 6, 2, 100, [([0, 1], 64)]),
+        # one tile, but the 128-thread cell's bound lands a step bucket up
+        ("tpu", [16, 64, 128], 6, 1, 3900, [([0, 1], 64), ([2], 128)]),
+        # a cohort that holds 128 cells takes no more columns, narrowest
+        # columns first
+        ("tpu", [32, 8, 64, 16], 64, 1, 100,
+         [([1, 3], 16), ([0, 2], 64)]),
+        ("tpu", [8, 16, 32, 64], 128, 1, 100,
+         [([0], 8), ([1], 16), ([2], 32), ([3], 64)]),
+        # on the CPU, power-of-two buckets as before, whatever the cells
+        ("cpu", [16, 24, 32, 48, 64], 6, 1, 100,
+         [([0], 16), ([1, 2], 32), ([3, 4], 64)]),
+        ("cpu", [32, 8, 64, 16], 128, 1, 100,
+         [([1], 8), ([3], 16), ([0], 32), ([2], 64)]),
+    ])
+    def test_thread_buckets_follow_the_platform(self, monkeypatch, backend,
+                                                cands, n_lat, n_cores, n_ops,
+                                                want):
+        """The partition ``_cohorts`` returns, by platform.  Every op of
+        the trace is one suboperation, so a cell's step bound is its
+        ``warmup + n_ops + slots`` op window rounded up to 4096."""
+        monkeypatch.setattr(replay_jax.jax, "default_backend",
+                            lambda: backend)
+        n = 50
+        flat = CompiledTrace.from_columns(
+            np.full(n, CPU, dtype=np.int8), np.full(n, 1e-6),
+            np.arange(n + 1, dtype=np.int64))
+        got = replay_jax._cohorts(flat, cands, n_lat, n_ops, None, n_cores,
+                                  True)
+        assert [(cols, T_max) for cols, T_max, _ in got] == want
+        for cols, T_max, steps in got:
+            assert steps == replay_jax._steps_bound(
+                flat, n_ops, 2 * T_max * n_cores, T_max * n_cores)
+
+    def test_lane_tiled_grid_bit_identical(self, lsm_small, monkeypatch):
+        """The lane-tiled partition, run on the CPU, merges the three
+        power-of-two cohorts into one 24-wide plane and changes no bit;
+        the records count the padding it costs."""
+        cfg = SimConfig(P=12, seed=7)
+        lats, cands = [0.5 * US, 5 * US], [4, 8, 24]
+        pow2 = sweep_grid(cfg, lsm_small.trace, lats, cands, n_ops=500)
+        monkeypatch.setattr(replay_jax, "_lane_tiled", lambda: True)
+        lane = sweep_grid(cfg, lsm_small.trace, lats, cands, n_ops=500)
+        for fld in ("throughput", "time", "mem_stall_total",
+                    "mem_accesses"):
+            assert np.array_equal(getattr(lane, fld), getattr(pow2, fld)), fld
+        assert [(c.cells, c.T_max, c.thread_slots)
+                for c in pow2.record.cohorts] == [(2, 4, 8), (2, 8, 16),
+                                                  (2, 24, 48)]
+        (c,) = lane.record.cohorts
+        assert (c.cells, c.T_max, c.thread_slots) == (6, 24, 72)
+        assert pow2.record.slot_fill == 1.0
+        assert lane.record.slot_fill == 72 / 144
+
     def test_host_devices_validation(self, lsm_small):
         with pytest.raises(ValueError, match="host_devices"):
             sweep_grid(SimConfig(), lsm_small.trace, [1 * US], [8],
