@@ -47,7 +47,6 @@ class TreeIndexStore:
         self.key = np.empty(n_keys, dtype=np.int64)
         self.left = np.full(n_keys, -1, dtype=np.int64)
         self.right = np.full(n_keys, -1, dtype=np.int64)
-        self.node_of: dict[int, int] = {}
         self._n_nodes = 0
         for k in order.tolist():
             self._insert(int(k))
@@ -57,7 +56,6 @@ class TreeIndexStore:
         """Untraced build-time insert; returns hop count."""
         i = self._n_nodes
         self.key[i] = k
-        self.node_of[k] = i
         self._n_nodes += 1
         s = int(self.sprig_of[k])
         cur = self.root[s]

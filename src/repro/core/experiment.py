@@ -31,10 +31,12 @@ seconds happens inside :class:`Experiment`.
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import io
 import json
 import math
+import time
 from dataclasses import dataclass, field
 from typing import Any, Sequence
 
@@ -51,6 +53,7 @@ __all__ = [
     "RunOptions",
     "SweepRow",
     "RunArtifact",
+    "EngineRecord",
     "Experiment",
     "run_scenario",
     "default_scenario",
@@ -316,16 +319,49 @@ class SweepRow:
         return f"L{self.L_us:g}us"
 
 
+@dataclass(frozen=True)
+class EngineRecord:
+    """The engine layer of one :meth:`Experiment.run`.
+
+    The ``t_*`` fields are host-clock (:func:`time.perf_counter_ns`) edges
+    of its two phases, each also a profiler span of the same name on the
+    jax backend: ``engine_build`` (:meth:`Experiment.build`: the engine's
+    untraced bulk load and the workload's key stream) and
+    ``engine_record`` (:func:`~repro.core.engines.run_trace`: running the
+    stream through the engine and recording its suboperations).  The
+    counters describe the recorded trace: its operations, slow-memory
+    hops, SSD accesses, and the suboperations of its longest operation.
+    """
+
+    t_build: int
+    t_record: int
+    t_done: int
+    n_ops: int
+    n_mem: int
+    n_io: int
+    max_op_subops: int
+
+    @property
+    def build_ns(self) -> int:
+        return self.t_record - self.t_build
+
+    @property
+    def record_ns(self) -> int:
+        return self.t_done - self.t_record
+
+
 @dataclass
 class RunArtifact:
     """Everything one experiment run produced, as serializable data.
 
-    ``points`` / ``trace_result`` / ``grid_records`` are live in-process
-    handles (the raw :class:`SweepPoint` list, the :class:`TraceResult`,
-    and one :class:`~repro.core.sim.replay_jax.GridRecord` per jax grid
-    call of the run -- one per cluster node -- with its host phases and
-    per-cohort scan steps) populated by :meth:`Experiment.run`; they are
-    excluded from equality and JSON, so
+    ``points`` / ``trace_result`` / ``grid_records`` / ``engine_record``
+    are live in-process handles (the raw :class:`SweepPoint` list, the
+    :class:`TraceResult`, one
+    :class:`~repro.core.sim.replay_jax.GridRecord` per jax grid call of
+    the run -- one per cluster node -- with its host phases and
+    per-cohort scan steps, and the :class:`EngineRecord` of the engine
+    layer) populated by :meth:`Experiment.run`; they are excluded from
+    equality and JSON, so
     ``RunArtifact.from_json(a.to_json()) == a`` holds.
     """
 
@@ -344,6 +380,8 @@ class RunArtifact:
     trace_result: TraceResult | None = field(
         default=None, compare=False, repr=False)
     grid_records: tuple = field(default=(), compare=False, repr=False)
+    engine_record: EngineRecord | None = field(
+        default=None, compare=False, repr=False)
 
     def __post_init__(self):
         self.rows = tuple(
@@ -472,8 +510,19 @@ class Experiment:
 
     def run(self) -> RunArtifact:
         s, o = self.scenario, self.options
-        store, wl = self.build()
-        tr = run_trace(store, wl, warmup_frac=s.warmup_frac)
+        span = _span_opener(o.backend)
+        t_build = time.perf_counter_ns()
+        with span("engine_build"):
+            store, wl = self.build()
+        t_record = time.perf_counter_ns()
+        with span("engine_record"):
+            tr = run_trace(store, wl, warmup_frac=s.warmup_frac)
+        t_done = time.perf_counter_ns()
+        counts = tr.trace.counts()
+        engine = EngineRecord(
+            t_build=t_build, t_record=t_record, t_done=t_done,
+            n_ops=tr.trace.n_ops, n_mem=counts["MEM"], n_io=counts["PREIO"],
+            max_op_subops=int(np.diff(tr.trace.bounds).max()))
         p = tr.op_params(store.times, P=s.P, T_sw=s.T_sw_us * US)
         cfg = s.sim_config()
         arrival = s.arrival_spec()
@@ -547,7 +596,18 @@ class Experiment:
             points=pts,
             trace_result=tr,
             grid_records=tuple(grids),
+            engine_record=engine,
         )
+
+
+def _span_opener(backend: str):
+    """Profiler spans on the jax backend; none on the loop backend, whose
+    sweep workers never import jax."""
+    if backend != "jax":
+        return lambda name: contextlib.nullcontext()
+    import jax
+
+    return jax.profiler.TraceAnnotation
 
 
 def _summary_tail(summ, offered: float | None,

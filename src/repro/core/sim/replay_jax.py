@@ -632,44 +632,49 @@ def _cohorts(source: CompiledTrace, candidates: Sequence[int], n_lat: int,
              n_ops: int, warmup_ops: int | None, n_cores: int,
              bucket_threads: bool) -> list[tuple[list[int], int, int]]:
     """Partition candidate columns (``n_lat`` cells each) into scan
-    cohorts: ``(cols, T_max, steps)`` groups sharing a thread bucket *and*
-    a step bound.
+    cohorts: ``(cols, T_max, steps)`` groups sharing a thread bucket (on
+    the CPU, a step bound too).
 
     The thread buckets are :func:`_thread_bucket`'s, which the platform
-    decides; within a bucket, candidates whose per-cell worst-case bound
-    lands in a different ``_STEP_BUCKET`` split into their own cohort, so
-    a cohort's early exit is never held open by a cell with a structurally
-    larger bound (uneven warmups are the common case: warmup defaults to
-    ``2 * threads * cores``).  Lane-tiled, a group's columns merge,
-    narrowest first, only while the cohort holds fewer than
-    ``_MERGE_CELLS`` cells: past that a step's fixed cost is small beside
-    its per-cell cost, and a wider cohort costs more than the ones it
-    would replace.  Per-cell RNG purity makes any partition
-    result-invariant; ``bucket_threads=False`` collapses everything into
-    the single monolithic scan (one ``T_max``, one global bound)."""
+    decides.  On the CPU, within a bucket, candidates whose per-cell
+    worst-case bound lands in a different ``_STEP_BUCKET`` split into
+    their own cohort, so a cohort's early exit is never held open by a
+    cell with a structurally larger bound (uneven warmups are the common
+    case: warmup defaults to ``2 * threads * cores``).  Lane-tiled, a
+    step's fixed cost outweighs the steps a cell idles at the end, so a
+    bucket's columns share cohorts whatever their bounds (a cohort's
+    bound is its largest), and merge, narrowest first, only while the
+    cohort holds fewer than ``_MERGE_CELLS`` cells: past that a step's
+    fixed cost is small beside its per-cell cost, and a wider cohort
+    costs more than the ones it would replace.  Per-cell RNG purity makes
+    any partition result-invariant; ``bucket_threads=False`` collapses
+    everything into the single monolithic scan (one ``T_max``, one global
+    bound)."""
     if not bucket_threads:
         T_max = max(candidates)
         warm = (warmup_ops if warmup_ops is not None
                 else 2 * T_max * n_cores)
         steps = _steps_bound(source, n_ops, warm, T_max * n_cores)
         return [(list(range(len(candidates))), T_max, steps)]
+    tiled = _lane_tiled()
     groups: dict[tuple[int, int], list[int]] = {}
+    bound = []
     for j, c in enumerate(candidates):
-        b = _thread_bucket(c, n_cores)
         warm = warmup_ops if warmup_ops is not None else 2 * c * n_cores
-        steps = _steps_bound(source, n_ops, warm, c * n_cores)
-        groups.setdefault((b, steps), []).append(j)
+        bound.append(_steps_bound(source, n_ops, warm, c * n_cores))
+        key = (_thread_bucket(c, n_cores), 0 if tiled else bound[j])
+        groups.setdefault(key, []).append(j)
     cohorts = []
-    for (_, steps), ix in sorted(groups.items()):
+    for _, ix in sorted(groups.items()):
         parts = [ix]
-        if _lane_tiled():
+        if tiled:
             parts = [[]]
             for j in sorted(ix, key=lambda j: candidates[j]):
                 if len(parts[-1]) * n_lat >= _MERGE_CELLS:
                     parts.append([])
                 parts[-1].append(j)
-        cohorts += [(cols, max(candidates[j] for j in cols), steps)
-                    for cols in parts]
+        cohorts += [(cols, max(candidates[j] for j in cols),
+                     max(bound[j] for j in cols)) for cols in parts]
     return cohorts
 
 

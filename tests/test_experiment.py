@@ -2,6 +2,10 @@
 run artifacts, CLI discovery flags, and matrix-sweep equivalence."""
 import dataclasses
 import json
+import os
+import subprocess
+import sys
+import textwrap
 from pathlib import Path
 
 import numpy as np
@@ -271,6 +275,44 @@ class TestRunArtifact:
                 == dataclasses.replace(art, grid_records=()).to_dict())
         again = RunArtifact.from_json(art.to_json())
         assert again == art and again.grid_records == ()
+
+    @pytest.mark.parametrize("engine", ["hash-index", "tree-index"])
+    @pytest.mark.parametrize("backend", ["loop", "jax"])
+    def test_engine_record(self, engine, backend):
+        """The engine layer's edges are ordered and its counters are the
+        recorded trace's, on either backend, outside equality and JSON."""
+        art = run_scenario(default_scenario(engine, **SMALL),
+                           RunOptions(backend=backend))
+        rec, tr = art.engine_record, art.trace_result
+        assert 0 < rec.t_build < rec.t_record < rec.t_done
+        assert rec.n_ops == tr.trace.n_ops
+        assert rec.n_mem / rec.n_ops == tr.mem_per_op
+        assert rec.n_io / rec.n_ops == tr.io_per_op
+        assert rec.max_op_subops == max(
+            len(op.subops) for op in tr.trace.to_ops())
+        assert "engine_record" not in art.to_dict()
+        assert RunArtifact.from_json(art.to_json()) == art
+
+    def test_loop_run_leaves_jax_unloaded(self):
+        """The engine layer's profiler spans exist on the jax backend
+        only: a loop run (and so every sweep worker) never imports jax."""
+        code = textwrap.dedent("""
+            import sys
+            from repro.core.experiment import (Experiment, RunOptions,
+                                               default_scenario)
+            sc = default_scenario("tree-index", n_keys=4000, n_wl_ops=1500,
+                                  latencies_us=(0.1, 5),
+                                  thread_candidates=(16,), n_ops=300)
+            art = Experiment(sc, RunOptions(processes=1)).run()
+            assert art.engine_record.n_ops > 0
+            print("jax" in sys.modules)
+        """)
+        env = dict(os.environ,
+                   PYTHONPATH=str(Path(__file__).parent.parent / "src"))
+        out = subprocess.run([sys.executable, "-c", code], env=env,
+                             capture_output=True, text=True, timeout=300)
+        assert out.returncode == 0, out.stderr[-4000:]
+        assert out.stdout.strip().splitlines()[-1] == "False"
 
     def test_run_options_cache_dir(self, tmp_path):
         sc = default_scenario("hash-index", n_ssd=2, **SMALL)

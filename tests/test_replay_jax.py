@@ -428,8 +428,10 @@ class TestCohortEarlyExit:
          [([0, 1, 2, 3, 4], 128), ([5], 256)]),
         # slots count every core: 96 and 128 slots share one tile
         ("tpu", [48, 64], 6, 2, 100, [([0, 1], 64)]),
-        # one tile, but the 128-thread cell's bound lands a step bucket up
-        ("tpu", [16, 64, 128], 6, 1, 3900, [([0, 1], 64), ([2], 128)]),
+        # one tile: the 128-thread cell's bound lands a step bucket up,
+        # and the cohort takes it
+        ("tpu", [16, 64, 128], 6, 1, 3900, [([0, 1, 2], 128)]),
+        ("tpu", [40, 64], 6, 1, 3970, [([0, 1], 64)]),
         # a cohort that holds 128 cells takes no more columns, narrowest
         # columns first
         ("tpu", [32, 8, 64, 16], 64, 1, 100,
@@ -441,6 +443,9 @@ class TestCohortEarlyExit:
          [([0], 16), ([1, 2], 32), ([3, 4], 64)]),
         ("cpu", [32, 8, 64, 16], 128, 1, 100,
          [([1], 8), ([3], 16), ([0], 32), ([2], 64)]),
+        # on the CPU one power-of-two bucket splits where the bounds
+        # land in different step buckets
+        ("cpu", [40, 64], 6, 1, 3970, [([0], 40), ([1], 64)]),
     ])
     def test_thread_buckets_follow_the_platform(self, monkeypatch, backend,
                                                 cands, n_lat, n_cores, n_ops,
