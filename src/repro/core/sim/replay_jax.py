@@ -108,10 +108,11 @@ __all__ = ["TraceArrays", "GridResult", "GridRecord", "CohortRecord",
 
 _STEP_BUCKET = 4096     # scan lengths round up to this (compile-cache reuse)
 _LANES = 128            # lanes of a TPU vector register (thread-slot axis)
-# Lane-tiled cohorts stop merging at this many cells.  On one TPU v5e a
-# 128-cell step costs ~78 us, a 12-cell step ~27 us: little of the 128-cell
-# step is fixed cost, and one 384-cell step (262 us) cost more than three
-# 128-cell steps (235 us together).
+# Lane-tiled cohorts stop merging at this many cells.  On one TPU v5e, in
+# the scatter step form, a 128-cell step cost ~78 us, a 12-cell step ~27 us:
+# little of the 128-cell step was fixed cost, and one 384-cell step (262 us)
+# cost more than three 128-cell steps (235 us together).  Not re-measured
+# in the select form, whose 128-cell step costs ~17 us.
 _MERGE_CELLS = 128
 _PAD_SENTINEL = CPU     # padded suboperations are inert plain-CPU entries
 
@@ -228,12 +229,15 @@ class GridRecord:
     ``grid_lower`` phase (lowering the trace, partitioning the cohorts,
     uploading the arrival array; a profiler span too) and one
     :class:`CohortRecord` per cohort, in run order, whose planes are
-    ``n_cores * T_max`` slots wide.  The call's step counters and
-    ``slot_fill`` derive from the cohort records."""
+    ``n_cores * T_max`` slots wide.  ``step_form`` is the op form every
+    cohort's scan step ran in (``"select"`` or ``"scatter"``, see
+    :func:`_step_form`).  The call's step counters and ``slot_fill``
+    derive from the cohort records."""
 
     t_lower: int
     t_lowered: int
     n_cores: int
+    step_form: str
     cohorts: tuple[CohortRecord, ...]
 
     @property
@@ -393,7 +397,7 @@ def _uniform(key, shape):
 def _grid_body(kinds, durs, op_starts, op_ends, n_trace,
                L_mem_g, nthr_g, warm_g, n_ops, dyn, key, stream_ids, arr, *,
                T_max, P, n_ssd, steps, unroll, substeps, use_pallas,
-               early_exit, n_cores,
+               early_exit, n_cores, step_form,
                has_eps, has_rho, has_jitter, has_rio, has_bio, has_bmem,
                has_lock, has_arr=False, has_lat=False, has_deadline=False,
                has_degrade=False):
@@ -506,7 +510,7 @@ def _grid_body(kinds, durs, op_starts, op_ends, n_trace,
         has_jitter=has_jitter, has_rio=has_rio, has_bio=has_bio,
         has_bmem=has_bmem, has_lock=has_lock, has_arr=has_arr,
         has_lat=has_lat, has_deadline=has_deadline, has_degrade=has_degrade,
-        onehot_updates=use_pallas, eager_wmin=use_pallas, n_cores=n_cores)
+        step_form=step_form, n_cores=n_cores)
 
     if use_pallas:
         def block(s, ub):
@@ -575,7 +579,7 @@ def _grid_body(kinds, durs, op_starts, op_ends, n_trace,
 
 _STATIC_GRID_ARGS = (
     "T_max", "P", "n_ssd", "steps", "unroll", "substeps", "use_pallas",
-    "early_exit", "n_cores",
+    "early_exit", "n_cores", "step_form",
     "has_eps", "has_rho", "has_jitter", "has_rio", "has_bio", "has_bmem",
     "has_lock", "has_arr", "has_lat", "has_deadline", "has_degrade")
 
@@ -613,6 +617,21 @@ def _lane_tiled() -> bool:
     the vector lanes and a scan step pays a fixed cost for its string of
     small ops, whatever the plane's width up to one tile."""
     return jax.default_backend() == "tpu"
+
+
+def _step_form(use_pallas: bool) -> str:
+    """The op form of the scan step.  ``"select"`` where the backend is
+    lane-tiled (see :func:`_lane_tiled`) and in the Pallas kernel: every
+    thread-plane read is a one-hot select and every write a one-hot
+    merge, and the idle-skip runs straight-line, so a step is one string
+    of vector ops with no scatter, no row gather and no branch.  On one
+    TPU v5e that is 23-45% fewer device ops a step, and a step costs 0.6
+    (6 cells) to 0.2 (128 cells) of the scatter form's: the TPU works
+    through a gather's or a scatter's rows one at a time.  ``"scatter"``
+    on the CPU, where a one-hot pass costs the whole plane: row gathers
+    and scatters, and a run-time branch around the idle-skip.  Both forms
+    give identical values."""
+    return "select" if use_pallas or _lane_tiled() else "scatter"
 
 
 def _thread_bucket(n_threads: int, n_cores: int) -> int:
@@ -860,6 +879,7 @@ def sweep_grid(
                     f"fused step's span packing supports < 2**{SPAN_SHIFT}")
             cohorts = _cohorts(source, candidates, n_lat, n_ops,
                                warmup_ops, cfg.n_cores, bucket_threads)
+            step_form = _step_form(use_pallas)
             arr = jnp.asarray(arr_np)
         t_lowered = time.perf_counter_ns()
         for cols, T_max, steps in cohorts:
@@ -894,7 +914,8 @@ def sweep_grid(
                 T_max=T_max, P=cfg.P, n_ssd=cfg.n_ssd, steps=steps,
                 unroll=unroll, substeps=substeps if use_pallas else 0,
                 use_pallas=use_pallas, early_exit=early_exit,
-                n_cores=cfg.n_cores, has_arr=has_arr, has_lat=has_lat,
+                n_cores=cfg.n_cores, step_form=step_form,
+                has_arr=has_arr, has_lat=has_lat,
                 has_deadline=has_deadline, **_make_flags(cfg),
             )
             args = dict(cells=G, T_max=T_max, steps_bound=steps)
@@ -964,7 +985,8 @@ def sweep_grid(
         mem_stall_total=stall,
         mem_accesses=macc,
         ops=n_ops,
-        record=GridRecord(t_lower, t_lowered, cfg.n_cores, tuple(records)),
+        record=GridRecord(t_lower, t_lowered, cfg.n_cores, step_form,
+                          tuple(records)),
         p50=p50 if has_lat else None,
         p90=p90 if has_lat else None,
         p99=p99 if has_lat else None,

@@ -22,11 +22,15 @@ Pallas kernel (``replay_jax.sweep_grid`` refuses ``use_pallas`` there; the
 CPU the kernel runs in ``interpret=True`` mode, which is how the tests
 validate it bit-for-bit against the jnp path on tiny grids
 (``tests/test_replay_jax.py``).  Bit-identity holds by construction: both
-paths execute ``make_substep``'s ops in the same order; the kernel variant
-only switches the per-row gather/scatter *implementation* to one-hot
-select/merge forms (``onehot_updates``), which produce bit-identical values
-(a one-term masked sum is exact) while staying on the VPU-friendly subset
-of ops.
+paths execute ``make_substep``'s ops in the same order.  The step has two
+op forms with identical values (``step_form``): ``"scatter"``, per-row
+gathers and scatters with a run-time branch around the idle-skip, and
+``"select"``, one-hot selects and merges with the idle-skip run
+straight-line, the VPU-friendly subset.  The kernel always uses the
+select form; so does the jnp scan on the TPU (``replay_jax._step_form``),
+where it takes the scatters, the row gathers and the ``conditional`` out
+of every step.  The CPU's jnp scan keeps the scatter form, since a one-hot
+pass costs a whole plane there.
 
 Lexicographic minima
 --------------------
@@ -149,8 +153,8 @@ def unpack_span(span):
 
 def make_substep(*, n_u, n_ssd, has_eps, has_rho, has_jitter, has_rio,
                  has_bio, has_bmem, has_lock, has_arr=False, has_lat=False,
-                 has_deadline=False, has_degrade=False, onehot_updates=False,
-                 eager_wmin=False, n_cores=1):
+                 has_deadline=False, has_degrade=False, step_form="scatter",
+                 n_cores=1):
     """Build the scheduler substep body, specialized on the static config.
 
     The returned ``substep(state, u, kd, se, arr, nthr_g, n_trace,
@@ -180,12 +184,13 @@ def make_substep(*, n_u, n_ssd, has_eps, has_rho, has_jitter, has_rio,
     IOs submitted at ``now >= T_degrade`` (mid-run device slowdown; same
     submission-time rule as the loops' ``SSDClocks.submit``).
 
-    ``onehot_updates`` switches the per-row thread-plane gathers/scatters
-    to bit-identical one-hot select/merge forms (the Pallas kernel's
-    VPU-friendly subset); ``eager_wmin`` always runs the starved-cell
-    idle-skip re-derivation instead of branching on whether any cell is
-    starved (kernels prefer straight-line code; the resulting values are
-    identical either way).
+    ``step_form="select"`` switches the per-row thread-plane
+    gathers/scatters to bit-identical one-hot select/merge forms and
+    always runs the starved-cell idle-skip re-derivation instead of
+    branching on whether any cell is starved: straight-line code, the
+    VPU-friendly subset that the Pallas kernel and the TPU's jnp scan run.
+    ``"scatter"`` keeps the gathers, the scatters and the branch.  The
+    resulting values are identical either way.
 
     ``n_cores > 1`` adds a core axis: thread planes become ``(G, C*T)``
     core-major (the column is the global tid), the prefetch window and
@@ -199,27 +204,38 @@ def make_substep(*, n_u, n_ssd, has_eps, has_rho, has_jitter, has_rio,
     single-core step body on that core's thread segment.  The
     ``n_cores == 1`` path is byte-for-byte the pre-existing substep.
     """
+    if step_form not in ("scatter", "select"):
+        raise ValueError(f"step_form must be 'scatter' or 'select', "
+                         f"got {step_form!r}")
+    onehot = step_form == "select"
     has_io_clock = has_rio or has_bio
     multicore = n_cores > 1
     f = jnp.float64
     i4 = jnp.int32
 
     def sel_thread(plane, tid):
-        """``plane[g, tid[g]]`` -- gather, or a one-term masked sum."""
-        if onehot_updates:
+        """``plane[g, tid[g]]`` -- gather, or a one-hot select.
+
+        The select is a row max with every other slot at ``-inf``: it does
+        no arithmetic on any value, so it is exact for every value but NaN
+        (none is stored), ``+inf`` included.  A one-term masked *sum* is
+        not, on XLA:TPU: its emulated float64 is a float32 pair, and an
+        addition that meets the wake plane's ``+inf`` can leave NaN in
+        the low word."""
+        if onehot:
             T = plane.shape[1]
             hot = jax.lax.broadcasted_iota(i4, (plane.shape[0], T), 1) \
                 == tid[:, None]
             if plane.ndim == 3:
-                return jnp.sum(jnp.where(hot[:, :, None], plane, 0.0), 1)
-            return jnp.sum(jnp.where(hot, plane, 0.0), 1)
+                hot = hot[:, :, None]
+            return jnp.max(jnp.where(hot, plane, -jnp.inf), 1)
         if plane.ndim == 3:
             return jnp.take_along_axis(plane, tid[:, None, None], 1)[:, 0]
         return jnp.take_along_axis(plane, tid[:, None], 1)[:, 0]
 
     def upd_thread(plane, tid, val):
         """``plane.at[g, tid[g]].set(val[g])`` -- scatter or one-hot merge."""
-        if onehot_updates:
+        if onehot:
             T = plane.shape[1]
             hot = jax.lax.broadcasted_iota(i4, (plane.shape[0], T), 1) \
                 == tid[:, None]
@@ -306,10 +322,10 @@ def make_substep(*, n_u, n_ssd, has_eps, has_rho, has_jitter, has_rio,
 
         # -- idle-skip: nothing ready, nothing eligible -> jump to the ------
         # earliest wake-up and re-derive the keys.  Starvation is rare for
-        # healthy thread counts, so the jnp path branches around the second
-        # pass at run time; the kernel path runs it straight-line.  The
-        # values agree either way: a cell that did not starve re-derives
-        # identical keys from an unchanged ``now``.
+        # healthy thread counts, so the CPU's jnp scan branches around the
+        # second pass at run time; the kernel and the TPU's scan run it
+        # straight-line.  The values agree either way: a cell that did not
+        # starve re-derives identical keys from an unchanged ``now``.
         starved = head >= BIG
 
         def skip(now_v):
@@ -317,7 +333,7 @@ def make_substep(*, n_u, n_ssd, has_eps, has_rho, has_jitter, has_rio,
             now2 = jnp.where(starved, jnp.maximum(now_v, w_min), now_v)
             return (now2,) + ring_head(now2)
 
-        if eager_wmin:
+        if onehot:
             now, head, slot_tid = skip(now)
         else:
             now, head, slot_tid = jax.lax.cond(
@@ -394,7 +410,7 @@ def make_substep(*, n_u, n_ssd, has_eps, has_rho, has_jitter, has_rio,
                           * HIST_INV_LN_RATIO),
                 0, HIST_BINS - 1).astype(i4)
             inc = jnp.where(rec, 1.0, 0.0)
-            if onehot_updates:
+            if onehot:
                 hot = jax.lax.broadcasted_iota(
                     i4, lat_hist.shape, 1) == b[:, None]
                 lat_hist = lat_hist + jnp.where(hot, inc[:, None], 0.0)
@@ -561,7 +577,7 @@ def fused_steps(substep, state, u_block, kd, se, arr, n_trace, L_mem_g,
     """Advance ``state`` by K substeps in one ``pallas_call`` invocation.
 
     ``substep`` must come from :func:`make_substep` (built with
-    ``onehot_updates=True, eager_wmin=True`` for the kernel-friendly op
+    ``step_form="select"`` for the kernel-friendly op
     subset); ``u_block`` is the ``(K, n_u, G)`` uniform feed; ``arr`` the
     shared arrival vector (a 1-wide dummy closed loop) and ``nthr_g`` the
     per-cell thread counts.  All planes are kernel refs: they are read

@@ -1089,3 +1089,61 @@ class TestOpenLoopGrid:
         with pytest.raises(ValueError, match="deadline"):
             sweep_grid(cfg, hash_small.trace, [1 * US], [8], n_ops=400,
                        deadline=-1.0)
+
+
+# -- 5. the scan step's op form ----------------------------------------------
+
+STEP_FORM_CASES = {
+    **{engine: dict(engine=engine) for engine in ENGINES},
+    "open_loop_deadline_pct": dict(engine="hash-index", open_loop=True),
+    "ssd2_token_clocks": dict(engine="lsm", cfg=dict(
+        n_ssd=2, R_io=250e3, L_switch=0.3 * US)),
+    # one thread per core, long IOs: every core segment carries inactive
+    # and ready slots at +inf on the wake plane, and starved cores
+    # idle-skip to their parked thread's wake-up
+    "cores4_inf_wake": dict(engine="lsm", cands=[1, 3], cfg=dict(
+        n_cores=4, L_io=40 * US)),
+}
+STEP_FORM_FIELDS = ("throughput", "time", "mem_stall_total", "mem_accesses",
+                    "p50", "p90", "p99", "lat_max", "lat_count", "missed",
+                    "lat_hist")
+
+
+@pytest.mark.parametrize("case", sorted(STEP_FORM_CASES))
+def test_select_step_bit_identical_to_scatter_step(case, monkeypatch):
+    """The lane-tiled step (one-hot selects and merges over the thread
+    planes, the idle-skip straight-line), forced on the CPU, gives every
+    number of every cell the CPU's step (gathers, scatters, a branch
+    around the idle-skip) gives, bit for bit."""
+    spec = STEP_FORM_CASES[case]
+    sc = default_scenario(spec["engine"], n_keys=2_000, n_wl_ops=600)
+    store = available_engines()[spec["engine"]](sc.n_keys,
+                                                **sc.engine_kwargs)
+    wname, wkw = sc.resolved_workload()
+    trace = run_trace(store, workloads.create_workload(
+        wname, sc.n_keys, sc.n_wl_ops, **wkw)).trace
+    cfg = dataclasses.replace(sc.sim_config(), **spec.get("cfg", {}))
+    cands, n_ops = spec.get("cands", [4, 8]), 150
+    kw = {}
+    if spec.get("open_loop"):
+        arr_spec = ArrivalSpec(kind="poisson", rate=40e3, seed=5,
+                               deadline=100e-6)
+        kw = dict(arrivals=_arrival_array(arr_spec, cfg, cands, n_ops),
+                  collect_percentiles=True, deadline=arr_spec.deadline)
+
+    def grid():
+        return sweep_grid(cfg, trace, [1 * US, 5 * US], cands, n_ops=n_ops,
+                          **kw)
+
+    scatter = grid()
+    monkeypatch.setattr(replay_jax, "_lane_tiled", lambda: True)
+    select = grid()
+    assert scatter.record.step_form == "scatter"
+    assert select.record.step_form == "select"
+    for fld in STEP_FORM_FIELDS:
+        a, b = getattr(scatter, fld), getattr(select, fld)
+        assert (a is None) == (b is None), fld
+        if a is not None:
+            assert np.array_equal(a, b, equal_nan=True), fld
+    if spec.get("open_loop"):      # both sides of the deadline are hit
+        assert scatter.lat_count.sum() > 0 and scatter.missed.sum() > 0

@@ -6,8 +6,12 @@ Each case drives one ``chip_smoke.py`` phase through
 out, records the arguments of its widest cohort, and compiles
 ``replay_jax._grid_body`` for one described v5e chip at exactly those
 shapes.  What the TPU compiler refuses fails here instead of on the chip.
-Every case also asserts that the lowered program holds no 64-bit
-``bitcast_convert``: XLA:TPU emulates float64 and cannot rewrite one.
+The grid is partitioned and its step built as on the chip
+(``replay_jax._lane_tiled`` forced on).  Every case also asserts that the
+lowered program holds no 64-bit ``bitcast_convert`` (XLA:TPU emulates
+float64 and cannot rewrite one), and that the compiled scan step -- the
+inner ``while`` body -- runs in its select form: no ``scatter`` in it or
+in any computation it calls, and no ``conditional``.
 
 The topology is described inside a fixture, never at import time, so every
 pytest-xdist worker collects the same tests and only the worker running
@@ -92,8 +96,9 @@ def no_persistent_cache():
 
 
 def _record_grid_calls(monkeypatch, sc):
-    """Run ``sc`` on the jax backend with the grid program replaced by a
-    recorder; returns ``[(args, static), ...]``, one per cohort."""
+    """Run ``sc`` on the jax backend, partitioned and with its step built
+    as on the chip, with the grid program replaced by a recorder; returns
+    ``[(args, static), ...]``, one per cohort."""
     calls = []
 
     def record(*args, **static):
@@ -112,12 +117,55 @@ def _record_grid_calls(monkeypatch, sc):
         return out
 
     monkeypatch.setattr(replay_jax, "_run_grid", record)
+    monkeypatch.setattr(replay_jax, "_lane_tiled", lambda: True)
     Experiment(sc, RunOptions(backend="jax",
                               collect_percentiles=bool(sc.arrival))).run()
     return calls
 
 
 _BITCAST64 = re.compile(r"bitcast_convert.*(f64|i64|ui64)")
+_COMPUTATION = re.compile(r"^(?:ENTRY )?%?([\w.\-]+) \(.*\{$")
+_CALLED = re.compile(r"(?:calls|to_apply|body|condition|branch_computations"
+                     r"|true_computation|false_computation)"
+                     r"=(?:\{([^}]*)\}|(%[\w.\-]+))")
+_OPCODE = re.compile(r"^\s*(?:ROOT )?%[\w.\-]+ = .*? ([a-z][a-z0-9\-]*)\(")
+
+
+def _scan_step_opcodes(hlo_text):
+    """``(body, reached)``: the opcodes of the compiled inner ``while`` body
+    (the scan over a chunk's steps), and those of every computation it
+    reaches through ``calls=``, ``to_apply=``, branches and loops -- the
+    fusions it launches included."""
+    comps, name = {}, None
+    for line in hlo_text.splitlines():
+        m = _COMPUTATION.match(line)
+        if m:
+            name = m.group(1)
+            comps[name] = []
+        elif line.startswith("}"):
+            name = None
+        elif name:
+            comps[name].append(line)
+    bodies = [re.search(r"body=%?([\w.\-]+)", line).group(1)
+              for lines in comps.values() for line in lines
+              if " while(" in line
+              and 'op_name="jit(_grid_body)/while/body/while"' in line]
+    assert len(bodies) == 1, f"inner scan while bodies: {bodies}"
+
+    def opcodes(names):
+        return [m.group(1) for n in names for line in comps[n]
+                if (m := _OPCODE.match(line))]
+
+    seen, todo = set(), [bodies[0]]
+    while todo:
+        n = todo.pop()
+        if n not in seen:
+            seen.add(n)
+            todo += [c.strip().lstrip("%") for line in comps[n]
+                     for m in _CALLED.finditer(line)
+                     for c in (m.group(1) or m.group(2)).split(",")
+                     if c.strip()]
+    return opcodes(bodies), opcodes(seen)
 
 
 @pytest.mark.parametrize("case", sorted(CASES))
@@ -127,6 +175,7 @@ def test_grid_compiles_for_v5e(case, one_chip, no_persistent_cache,
     calls = _record_grid_calls(monkeypatch, CASES[case]())
     assert calls, "the jax backend never reached the grid program"
     args, static = max(calls, key=lambda c: c[1]["T_max"])
+    assert static["step_form"] == "select"
     shapes = jax.tree_util.tree_map(
         lambda a: jax.ShapeDtypeStruct(np.shape(a), a.dtype,
                                        sharding=one_chip), args)
@@ -136,3 +185,7 @@ def test_grid_compiles_for_v5e(case, one_chip, no_persistent_cache,
     assert not _BITCAST64.findall(hlo), "64-bit bitcast in the grid program"
     compiled = lowered.compile()
     assert compiled.memory_analysis() is not None
+    body, reached = _scan_step_opcodes(compiled.as_text())
+    assert "fusion" in body, "the inner while body was not found whole"
+    assert "conditional" not in body, "a run-time branch in the scan step"
+    assert "scatter" not in reached, "a scatter in the scan step"
