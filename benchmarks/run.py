@@ -30,10 +30,8 @@ keys include the backend and a code-version salt so stale cells never
 survive code changes), ``--adaptive`` warm-starts the per-point thread
 search from the previous latency point's winner, and ``--backend jax``
 replays a scenario's whole grid as one jitted jax call
-(see ``docs/SIMULATION.md``; ``--backend-pallas`` routes it through the
-fused whole-step scheduler kernel, ``--backend-unroll`` /
-``--backend-substeps`` tune scan unrolling and the steps-per-kernel
-batch, ``--backend-host-devices`` shards the grid's cells over XLA host
+(see ``docs/SIMULATION.md``; ``--backend-unroll`` tunes scan unrolling,
+``--backend-host-devices`` shards the grid's cells over XLA host
 CPU devices).  ``--artifact``
 writes the scenario run's full :class:`~repro.core.experiment.RunArtifact`
 (sweep table + trace stats + model predictions + config provenance) as
@@ -125,7 +123,7 @@ def run_scenario_cmd(scenario, artifact_out: str | None,
 
     ``backend_opts`` are jax-backend tuning fields of
     :class:`~repro.core.experiment.RunOptions`
-    (``use_pallas``/``unroll``/``substeps``/``host_devices``).
+    (``unroll``/``host_devices``).
     ``arrival`` (an :class:`~repro.core.sim.ArrivalSpec` dict from
     ``--arrival/--rate/--burst``) overrides the scenario's driver and
     switches on per-cell tail percentiles; ``cluster`` (a partial
@@ -288,26 +286,16 @@ def main() -> None:
                          "backend -- 'loop' interpreter cells (default) "
                          "or the vectorized 'jax' grid (one jitted call; "
                          "tolerance-equivalent, see docs/SIMULATION.md)")
-    ap.add_argument("--backend-pallas", action="store_true",
-                    help="with --backend jax: route the grid through the "
-                         "fused whole-step Pallas scheduler kernel "
-                         "(bit-identical to the jnp scan; interpreted "
-                         "off-TPU)")
     ap.add_argument("--backend-unroll", type=int, default=None, metavar="N",
-                    help="with --backend jax: scan unroll factor of the "
-                         "jnp path (default: sweep_grid's)")
-    ap.add_argument("--backend-substeps", type=int, default=None,
-                    metavar="K",
-                    help="with --backend jax: scheduler steps batched per "
-                         "fused-kernel invocation (must divide the RNG "
-                         "chunk; default: sweep_grid's)")
+                    help="with --backend jax: scan unroll factor "
+                         "(default: sweep_grid's)")
     ap.add_argument("--backend-host-devices", type=int, default=None,
                     metavar="N",
                     help="with --backend jax: shard grid cells over N XLA "
                          "host CPU devices (requires the process to have "
                          "been started with "
                          "XLA_FLAGS=--xla_force_host_platform_device_count"
-                         "=N or more; incompatible with --backend-pallas)")
+                         "=N or more)")
     ap.add_argument("--scenario", default=None, metavar="SPEC.json",
                     help="run one declarative scenario spec through the "
                          "experiment API instead of the paper figures")
@@ -425,9 +413,7 @@ def main() -> None:
         from repro.compile_cache import use_compile_cache
 
         use_compile_cache(Path(__file__).resolve().parents[1])
-    backend_opts = {"use_pallas": args.backend_pallas,
-                    "unroll": args.backend_unroll,
-                    "substeps": args.backend_substeps,
+    backend_opts = {"unroll": args.backend_unroll,
                     "host_devices": args.backend_host_devices}
 
     arrival = None

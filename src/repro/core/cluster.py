@@ -506,9 +506,7 @@ def sweep_cluster(
     collect_latency: bool = False,
     collect_percentiles: bool = False,
     arrival: ArrivalSpec | dict | None = None,
-    use_pallas: bool = False,
     unroll: int | None = None,
-    substeps: int | None = None,
     host_devices: int | None = None,
     grid_records: list | None = None,
 ) -> list[ClusterPoint]:
@@ -586,11 +584,9 @@ def sweep_cluster(
             row_of = {}
             grid = None
             if scalar_lis:
-                jax_opts = {"use_pallas": use_pallas}
+                jax_opts = {}
                 if unroll is not None:
                     jax_opts["unroll"] = unroll
-                if substeps is not None:
-                    jax_opts["substeps"] = substeps
                 if host_devices is not None:
                     jax_opts["host_devices"] = host_devices
                 grid = replay_jax.sweep_grid(

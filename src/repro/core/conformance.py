@@ -3,7 +3,7 @@ a seeded Scenario fuzzer that enforces it.
 
 The repo's core claim is that all three simulation backends realize the
 same prefetch+IO model: the generic event loop (``simulate``), the
-compiled fast loop (``simulate_compiled``), and the jax/Pallas grid
+compiled fast loop (``simulate_compiled``), and the jax grid
 (``replay_jax.sweep_grid``).  The *contracts* between backend pairs --
 which pairs are bit-identical and which are tolerance-bound, and at what
 op count the tolerance was measured -- were historically hardcoded across
@@ -15,7 +15,7 @@ constants it is built from); the tests, the fuzzer, and
 Two layers:
 
 * **Contract table** -- :class:`EquivalenceContract` rows keyed by pair
-  name.  Bit-identical pairs (``generic-vs-compiled``, ``pallas-vs-jnp``,
+  name.  Bit-identical pairs (``generic-vs-compiled``,
   ``trivial-cluster``) carry no tolerance; tolerance pairs
   (``jax-vs-loop``, ``cluster-jax-vs-loop``) carry a throughput bound
   documented at a reference op count plus tail bounds.  Sampling noise
@@ -161,13 +161,6 @@ CONTRACTS: dict[str, EquivalenceContract] = {
                 "is a mechanical specialization",
         ),
         EquivalenceContract(
-            name="pallas-vs-jnp",
-            pair=("sweep_grid(use_pallas=True)", "sweep_grid"),
-            bit_identical=True,
-            why="the fused Pallas kernel (interpreter mode on CPU) computes "
-                "the same lockstep update as the jnp scan, same dtypes",
-        ),
-        EquivalenceContract(
             name="trivial-cluster",
             pair=("sweep_cluster(n_nodes=1)", "sweep_latency"),
             bit_identical=True,
@@ -224,11 +217,6 @@ TAIL_QUEUE_GATE = 3.0
 # rows unless the sample is large enough to average over phases.
 PEAKY_TAIL_MIN_OPS = 400
 DIURNAL_PEAKY_AMPLITUDE = 0.5
-
-# Pallas interpreter mode executes the kernel step-by-step in Python, so
-# the bit-identity check clips the scenario to one grid cell and at most
-# this many ops -- the contract is per-cell, clipping loses no coverage.
-PALLAS_CLIP_OPS = 120
 
 
 # -- differential checks -----------------------------------------------------
@@ -403,36 +391,9 @@ def _check_jax(sc: Scenario) -> list[ConformanceFailure]:
     return fails
 
 
-def _pallas_clip(sc: Scenario) -> Scenario:
-    return replace(
-        sc,
-        latencies_us=(sc.latencies_us[0],),
-        thread_candidates=(sc.thread_candidates[0],),
-        n_ops=min(sc.n_ops, PALLAS_CLIP_OPS),
-    )
-
-
-def _check_pallas(sc: Scenario) -> list[ConformanceFailure]:
-    """Pallas-interpreter vs jnp-scan bit-identity on one clipped cell."""
-    clip = _pallas_clip(sc)
-    ref = _run(clip, backend="jax")
-    pal = _run(clip, backend="jax", use_pallas=True)
-    fails = []
-    for i, (rr, pr) in enumerate(zip(ref.rows, pal.rows)):
-        a, b = _row_core(rr), _row_core(pr)
-        if a != b:
-            diff = [k for k in a if a[k] != b[k]]
-            fails.append(ConformanceFailure(
-                "pallas", "pallas-vs-jnp",
-                f"row {i} ({rr.label()}) differs on {diff}: "
-                f"{ {k: (a[k], b[k]) for k in diff} }", sc))
-    return fails
-
-
 _CHECKS: dict[str, Callable[[Scenario], list]] = {
     "compiled": _check_compiled,
     "jax": _check_jax,
-    "pallas": _check_pallas,
 }
 CHECK_NAMES = tuple(_CHECKS)
 

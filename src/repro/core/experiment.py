@@ -261,10 +261,9 @@ class RunOptions:
     grid as one jitted scan whose per-cell throughput agrees with the loop
     backend within sampling tolerance, not bit-identically (the scientific
     spec is unchanged -- the measurement apparatus is; see
-    ``docs/SIMULATION.md``).  ``use_pallas``/``unroll``/``substeps``/
-    ``host_devices`` tune how the jax grid executes (fused whole-step
-    kernel, scan unrolling, steps per kernel invocation, shard_map over
-    host CPU devices) without changing any cell value."""
+    ``docs/SIMULATION.md``).  ``unroll``/``host_devices`` tune how the
+    jax grid executes (scan unrolling, shard_map over host CPU devices)
+    without changing any cell value."""
 
     processes: int | None = None       # sweep worker processes (None: auto)
     cache_dir: str | None = None       # on-disk sweep-cell cache
@@ -272,9 +271,7 @@ class RunOptions:
     collect_percentiles: bool = False  # p50/p90/p99 tail summary per cell
     adaptive: bool = False             # warm-started thread search
     backend: str = "loop"              # "loop" interpreters | "jax" grid
-    use_pallas: bool = False           # jax: fused whole-step kernel
-    unroll: int | None = None          # jax: jnp scan unroll (None: default)
-    substeps: int | None = None        # jax: steps per kernel invocation
+    unroll: int | None = None          # jax: scan unroll (None: default)
     host_devices: int | None = None    # jax: shard cells over N host devs
 
 
@@ -538,8 +535,7 @@ class Experiment:
                 s.latencies_sec(), s.thread_candidates, n_ops=s.n_ops,
                 backend=o.backend, collect_latency=o.collect_latency,
                 collect_percentiles=o.collect_percentiles, arrival=arrival,
-                use_pallas=o.use_pallas, unroll=o.unroll,
-                substeps=o.substeps, host_devices=o.host_devices,
+                unroll=o.unroll, host_devices=o.host_devices,
                 grid_records=grids,
             )
         else:
@@ -547,8 +543,8 @@ class Experiment:
                 cfg, tr.trace, s.latencies_sec(), s.thread_candidates,
                 n_ops=s.n_ops, processes=o.processes, cache_dir=o.cache_dir,
                 collect_latency=o.collect_latency, adaptive=o.adaptive,
-                backend=o.backend, use_pallas=o.use_pallas, unroll=o.unroll,
-                substeps=o.substeps, host_devices=o.host_devices,
+                backend=o.backend, unroll=o.unroll,
+                host_devices=o.host_devices,
                 arrival=arrival, collect_percentiles=o.collect_percentiles,
                 grid_records=grids,
             )

@@ -11,8 +11,8 @@ of a sweep, so an entire Fig. 9-style grid is a single compiled XLA program
 The recurrence
 --------------
 One scan step executes exactly one suboperation of one thread in every grid
-cell.  The step body itself lives in :mod:`repro.kernels.sched_step` (the
-fused whole-step scheduler kernel; see that module for the state layout):
+cell.  The step body itself lives in :mod:`repro.kernels.sched_step` (see
+that module for the state layout and the step's one op form):
 
   * thread selection: ready threads carry a monotone FIFO *stamp* (their
     last pop time), so a lexicographic (stamp, tid) minimum pops the ring
@@ -51,15 +51,6 @@ per-cell bound on the paper's default grid is enforced at
 ``n_ops=20_000`` by ``tests/test_replay_jax.py``.  Scalar
 latencies and single-core configs only; ``sweep_latency(backend="jax")``
 routes mixture latencies through the loop backend per-cell.
-
-``use_pallas=True`` runs the scan through the fused Pallas kernel
-(:func:`repro.kernels.sched_step.fused_steps`): the scheduler planes stay
-resident in VMEM across ``substeps`` inner steps per kernel invocation.
-It does not compile for TPU yet (its state is float64, which Mosaic does
-not lower; ROADMAP Queue 1 item 3), so :func:`sweep_grid` refuses it on
-any backend but CPU.  On CPU it runs in interpreter mode, which is far
-too slow for real sweeps but lets the tests validate the kernel
-bit-for-bit against the pure-jnp scan on tiny grids.
 
 Everything here is computed in float64 (``jax.enable_x64``): the state
 mixes ~second-scale clocks with 50 ns context switches, which float32
@@ -108,11 +99,12 @@ __all__ = ["TraceArrays", "GridResult", "GridRecord", "CohortRecord",
 
 _STEP_BUCKET = 4096     # scan lengths round up to this (compile-cache reuse)
 _LANES = 128            # lanes of a TPU vector register (thread-slot axis)
-# Lane-tiled cohorts stop merging at this many cells.  On one TPU v5e, in
-# the scatter step form, a 128-cell step cost ~78 us, a 12-cell step ~27 us:
-# little of the 128-cell step was fixed cost, and one 384-cell step (262 us)
-# cost more than three 128-cell steps (235 us together).  Not re-measured
-# in the select form, whose 128-cell step costs ~17 us.
+# Lane-tiled cohorts stop merging at this many cells.  On one TPU v5e, with
+# a step that scattered its thread-plane writes, a 128-cell step cost
+# ~78 us, a 12-cell step ~27 us: little of the 128-cell step was fixed
+# cost, and one 384-cell step (262 us) cost more than three 128-cell steps
+# (235 us together).  Not re-measured for the one-hot step, whose 128-cell
+# step costs ~17 us.
 _MERGE_CELLS = 128
 _PAD_SENTINEL = CPU     # padded suboperations are inert plain-CPU entries
 
@@ -229,15 +221,12 @@ class GridRecord:
     ``grid_lower`` phase (lowering the trace, partitioning the cohorts,
     uploading the arrival array; a profiler span too) and one
     :class:`CohortRecord` per cohort, in run order, whose planes are
-    ``n_cores * T_max`` slots wide.  ``step_form`` is the op form every
-    cohort's scan step ran in (``"select"`` or ``"scatter"``, see
-    :func:`_step_form`).  The call's step counters and ``slot_fill``
-    derive from the cohort records."""
+    ``n_cores * T_max`` slots wide.  The call's step counters and
+    ``slot_fill`` derive from the cohort records."""
 
     t_lower: int
     t_lowered: int
     n_cores: int
-    step_form: str
     cohorts: tuple[CohortRecord, ...]
 
     @property
@@ -396,8 +385,7 @@ def _uniform(key, shape):
 
 def _grid_body(kinds, durs, op_starts, op_ends, n_trace,
                L_mem_g, nthr_g, warm_g, n_ops, dyn, key, stream_ids, arr, *,
-               T_max, P, n_ssd, steps, unroll, substeps, use_pallas,
-               early_exit, n_cores, step_form,
+               T_max, P, n_ssd, steps, unroll, early_exit, n_cores,
                has_eps, has_rho, has_jitter, has_rio, has_bio, has_bmem,
                has_lock, has_arr=False, has_lat=False, has_deadline=False,
                has_degrade=False):
@@ -510,17 +498,11 @@ def _grid_body(kinds, durs, op_starts, op_ends, n_trace,
         has_jitter=has_jitter, has_rio=has_rio, has_bio=has_bio,
         has_bmem=has_bmem, has_lock=has_lock, has_arr=has_arr,
         has_lat=has_lat, has_deadline=has_deadline, has_degrade=has_degrade,
-        step_form=step_form, n_cores=n_cores)
+        n_cores=n_cores)
 
-    if use_pallas:
-        def block(s, ub):
-            return sk.fused_steps(sub, s, ub, kd, se, arr, n_trace,
-                                  L_mem_g, nthr_g, warm_g, n_ops,
-                                  dyn), None
-    else:
-        def step(s, u):
-            return sub(s, u, kd, se, arr, nthr_g, n_trace, L_mem_g,
-                       warm_g, n_ops, dyn), None
+    def step(s, u):
+        return sub(s, u, kd, se, arr, nthr_g, n_trace, L_mem_g,
+                   warm_g, n_ops, dyn), None
 
     def chunk(s, ck):
         if n_u:
@@ -529,9 +511,6 @@ def _grid_body(kinds, durs, op_starts, op_ends, n_trace,
             us = jnp.moveaxis(us, 0, -1)         # (G, CH, n_u) -> (CH, n_u, G)
         else:
             us = jnp.zeros((_RNG_CHUNK, 0, G), f)
-        if use_pallas:
-            ub = us.reshape(_RNG_CHUNK // substeps, substeps, n_u, G)
-            return jax.lax.scan(block, s, ub)
         return jax.lax.scan(step, s, us, unroll=unroll)
 
     n_chunks = steps // _RNG_CHUNK
@@ -578,8 +557,7 @@ def _grid_body(kinds, durs, op_starts, op_ends, n_trace,
 
 
 _STATIC_GRID_ARGS = (
-    "T_max", "P", "n_ssd", "steps", "unroll", "substeps", "use_pallas",
-    "early_exit", "n_cores", "step_form",
+    "T_max", "P", "n_ssd", "steps", "unroll", "early_exit", "n_cores",
     "has_eps", "has_rho", "has_jitter", "has_rio", "has_bio", "has_bmem",
     "has_lock", "has_arr", "has_lat", "has_deadline", "has_degrade")
 
@@ -615,23 +593,9 @@ def _lane_tiled() -> bool:
     """Whether a plane's per-step cost follows its 128-lane tiles rather
     than its element count: true on the TPU, where the thread-slot axis is
     the vector lanes and a scan step pays a fixed cost for its string of
-    small ops, whatever the plane's width up to one tile."""
+    small ops, whatever the plane's width up to one tile.  It decides the
+    cohort partition (:func:`_thread_bucket`, :func:`_cohorts`)."""
     return jax.default_backend() == "tpu"
-
-
-def _step_form(use_pallas: bool) -> str:
-    """The op form of the scan step.  ``"select"`` where the backend is
-    lane-tiled (see :func:`_lane_tiled`) and in the Pallas kernel: every
-    thread-plane read is a one-hot select and every write a one-hot
-    merge, and the idle-skip runs straight-line, so a step is one string
-    of vector ops with no scatter, no row gather and no branch.  On one
-    TPU v5e that is 23-45% fewer device ops a step, and a step costs 0.6
-    (6 cells) to 0.2 (128 cells) of the scatter form's: the TPU works
-    through a gather's or a scatter's rows one at a time.  ``"scatter"``
-    on the CPU, where a one-hot pass costs the whole plane: row gathers
-    and scatters, and a run-time branch around the idle-skip.  Both forms
-    give identical values."""
-    return "select" if use_pallas or _lane_tiled() else "scatter"
 
 
 def _thread_bucket(n_threads: int, n_cores: int) -> int:
@@ -705,9 +669,7 @@ def sweep_grid(
     n_ops: int = 5000,
     warmup_ops: int | None = None,
     *,
-    use_pallas: bool = False,
     unroll: int = 2,
-    substeps: int = 8,
     bucket_threads: bool = True,
     early_exit: bool = True,
     host_devices: int | None = None,
@@ -724,11 +686,7 @@ def sweep_grid(
     T_lock / SSD clocks).  Scalar latencies only; ``warmup_ops`` defaults
     per cell to ``2 * n_threads * n_cores``, like the loop backends.
 
-    ``use_pallas`` routes the scan through the fused whole-step kernel
-    (``substeps`` inner steps per kernel invocation); it runs on the CPU
-    backend only (interpreted), and raises elsewhere because the kernel
-    does not compile for TPU yet.  The default jnp scan path uses
-    ``unroll`` to amortize dispatch instead.
+    ``unroll`` unrolls the scan to amortize per-step dispatch.
     ``bucket_threads=True`` groups the candidates into cohorts by thread
     bucket (power-of-two on the CPU, 128-lane tiles on the TPU; see
     :func:`_thread_bucket`) and step bound;
@@ -743,8 +701,7 @@ def sweep_grid(
     ``--xla_force_host_platform_device_count`` -- *before* jax
     initializes); shards early-exit independently.  It raises unless the
     default backend is CPU, so it can never move an accelerator's grid
-    onto the host, and is incompatible with ``use_pallas`` (the
-    interpreted kernel cannot run under shard_map).
+    onto the host.
 
     ``arrivals`` (a monotone timestamp sequence, seconds -- see
     :func:`repro.core.sim.arrivals.generate_arrivals`) switches every
@@ -776,22 +733,11 @@ def sweep_grid(
             "the loop backend")
     if min(candidates) < 1:
         raise ValueError(f"thread candidates must be >= 1: {candidates}")
-    if substeps < 1 or _RNG_CHUNK % substeps:
-        raise ValueError(
-            f"substeps must divide the RNG chunk ({_RNG_CHUNK}): "
-            f"{substeps}")
 
     from repro.kernels.sched_step import SPAN_SHIFT
 
     n_lat, n_cand = len(latencies), len(candidates)
     backend = jax.default_backend()
-    if use_pallas and backend != "cpu":
-        raise ValueError(
-            f"use_pallas=True cannot run on the {backend} backend: the "
-            "fused sched_step kernel keeps its scheduler state in float64, "
-            "which Mosaic does not lower inside a Pallas kernel (moving "
-            "time to a 32-bit encoding is ROADMAP Queue 1 item 3); use the "
-            "default jnp scan (use_pallas=False)")
     n_dev = 1 if host_devices is None else int(host_devices)
     if n_dev < 1:
         raise ValueError(f"host_devices must be >= 1, got {host_devices}")
@@ -801,10 +747,6 @@ def sweep_grid(
                 f"host_devices={n_dev} shards the grid over host CPU "
                 f"devices, but the default backend is {backend}: drop "
                 "host_devices to run the grid on the accelerator")
-        if use_pallas:
-            raise ValueError(
-                "host_devices > 1 cannot run the interpreted Pallas "
-                "kernel under shard_map; drop use_pallas or the sharding")
         avail = len(jax.devices("cpu"))
         if n_dev > avail:
             raise ValueError(
@@ -876,10 +818,9 @@ def sweep_grid(
             if int(ta.op_ends[-1]) >= (1 << SPAN_SHIFT):
                 raise ValueError(
                     f"trace has {int(ta.op_ends[-1])} suboperations; the "
-                    f"fused step's span packing supports < 2**{SPAN_SHIFT}")
+                    f"step's span packing supports < 2**{SPAN_SHIFT}")
             cohorts = _cohorts(source, candidates, n_lat, n_ops,
                                warmup_ops, cfg.n_cores, bucket_threads)
-            step_form = _step_form(use_pallas)
             arr = jnp.asarray(arr_np)
         t_lowered = time.perf_counter_ns()
         for cols, T_max, steps in cohorts:
@@ -912,9 +853,7 @@ def sweep_grid(
                     for a in (L_mem_g, nthr_g, warm_g, stream_ids))
             static = dict(
                 T_max=T_max, P=cfg.P, n_ssd=cfg.n_ssd, steps=steps,
-                unroll=unroll, substeps=substeps if use_pallas else 0,
-                use_pallas=use_pallas, early_exit=early_exit,
-                n_cores=cfg.n_cores, step_form=step_form,
+                unroll=unroll, early_exit=early_exit, n_cores=cfg.n_cores,
                 has_arr=has_arr, has_lat=has_lat,
                 has_deadline=has_deadline, **_make_flags(cfg),
             )
@@ -985,8 +924,7 @@ def sweep_grid(
         mem_stall_total=stall,
         mem_accesses=macc,
         ops=n_ops,
-        record=GridRecord(t_lower, t_lowered, cfg.n_cores, step_form,
-                          tuple(records)),
+        record=GridRecord(t_lower, t_lowered, cfg.n_cores, tuple(records)),
         p50=p50 if has_lat else None,
         p90=p90 if has_lat else None,
         p99=p99 if has_lat else None,

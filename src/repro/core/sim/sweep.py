@@ -147,8 +147,8 @@ def _run_jax_cells(cfg: SimConfig, trace: CompiledTrace, latencies,
     ``grid_records`` when given; mixture-latency cells (which the jax
     backend does not model) run through the compiled loop per cell.
     ``jax_opts`` are extra ``sweep_grid`` tuning kwargs
-    (``use_pallas``/``unroll``/``substeps``) -- they select execution
-    strategy, never values."""
+    (``unroll``/``host_devices``) -- they select execution strategy,
+    never values."""
     from . import replay_jax   # deferred: jax is a heavyweight import
 
     k = len(candidates)
@@ -411,9 +411,7 @@ def sweep_latency(
     collect_latency: bool = False,
     adaptive: bool = False,
     backend: str = "loop",
-    use_pallas: bool = False,
     unroll: int | None = None,
-    substeps: int | None = None,
     host_devices: int | None = None,
     arrival: ArrivalSpec | dict | None = None,
     collect_percentiles: bool = False,
@@ -485,15 +483,13 @@ def sweep_latency(
         callable) and no latency/histogram collection; incompatible with
         ``adaptive=True``.  Cached cells are keyed per backend, so the two
         never answer for each other.
-    use_pallas, unroll, substeps, host_devices
+    unroll, host_devices
         Jax-backend execution tuning, forwarded to
-        :func:`~repro.core.sim.replay_jax.sweep_grid`: ``use_pallas``
-        routes the scan through the fused whole-step kernel (``substeps``
-        inner steps per kernel invocation; CPU interpreter only, refused
-        on other backends), ``unroll`` amortizes dispatch on the jnp scan
-        path, ``host_devices`` shard_maps the cell axis over that many
-        host CPU devices (CPU backend only; requires the process to have
-        been started with ``--xla_force_host_platform_device_count``).
+        :func:`~repro.core.sim.replay_jax.sweep_grid`: ``unroll``
+        amortizes dispatch on the scan, ``host_devices`` shard_maps the
+        cell axis over that many host CPU devices (CPU backend only;
+        requires the process to have been started with
+        ``--xla_force_host_platform_device_count``).
         ``None`` keeps ``sweep_grid``'s default.  Strategy knobs only -- cell
         values (and hence cache keys) do not depend on them; ignored by
         ``backend="loop"``.
@@ -600,11 +596,9 @@ def sweep_latency(
 
     # -- run missing cells ---------------------------------------------------
     if backend == "jax" and todo:
-        jax_opts = {"use_pallas": use_pallas}
+        jax_opts = {}
         if unroll is not None:
             jax_opts["unroll"] = unroll
-        if substeps is not None:
-            jax_opts["substeps"] = substeps
         if host_devices is not None:
             jax_opts["host_devices"] = host_devices
         _run_jax_cells(cfg, trace, latencies, candidates, n_ops,

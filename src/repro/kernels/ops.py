@@ -2,8 +2,9 @@
 
 The TPU is the *target*; on CPU (this container) every kernel runs in
 ``interpret=True`` mode, which executes the kernel body in Python for
-correctness validation against :mod:`repro.kernels.ref`. ``use_pallas``
-lets callers fall back to the pure-jnp paths in :mod:`repro.models.layers`.
+correctness validation against :mod:`repro.kernels.ref`.  These wrappers
+always run the Pallas kernels; a caller that wants a pure-jnp path imports
+it from :mod:`repro.models.layers` or :mod:`repro.kernels.ref` instead.
 """
 from __future__ import annotations
 

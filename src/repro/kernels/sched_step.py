@@ -1,36 +1,16 @@
-"""Fused whole-step scheduler kernel (batched over grid cells).
+"""The scheduler step of the jax sweep grid (batched over grid cells).
 
 :mod:`repro.core.sim.replay_jax` replays one scheduler *step* -- wake the
 completed parked threads, pop the FIFO ready ring, execute one suboperation
 (MEM stall / PREIO submit / op completion), issue the next prefetch -- for
-every cell of a latency x threads grid at once.  This module is that step,
-factored so the exact same arithmetic runs two ways:
-
-  * :func:`make_substep` builds the pure-jnp step body; the jax backend's
-    ``lax.scan`` path calls it directly (one call per step, ``unroll``
-    amortizing dispatch);
-  * :func:`fused_steps` wraps the same body in a single
-    ``pl.pallas_call`` that keeps all scheduler planes resident in
-    VMEM/registers while an inner ``fori_loop`` executes a batch of K
-    substeps per kernel invocation (the ``substeps`` knob), so the planes
-    do not round-trip through HBM between steps.
-
-On TPU the jnp scan is the path that compiles: the fused kernel does not,
-because its state is float64 and Mosaic lowers no 64-bit operand inside a
-Pallas kernel (``replay_jax.sweep_grid`` refuses ``use_pallas`` there; the
-32-bit time encoding that would lift this is ROADMAP Queue 1 item 3).  On
-CPU the kernel runs in ``interpret=True`` mode, which is how the tests
-validate it bit-for-bit against the jnp path on tiny grids
-(``tests/test_replay_jax.py``).  Bit-identity holds by construction: both
-paths execute ``make_substep``'s ops in the same order.  The step has two
-op forms with identical values (``step_form``): ``"scatter"``, per-row
-gathers and scatters with a run-time branch around the idle-skip, and
-``"select"``, one-hot selects and merges with the idle-skip run
-straight-line, the VPU-friendly subset.  The kernel always uses the
-select form; so does the jnp scan on the TPU (``replay_jax._step_form``),
-where it takes the scatters, the row gathers and the ``conditional`` out
-of every step.  The CPU's jnp scan keeps the scatter form, since a one-hot
-pass costs a whole plane there.
+every cell of a latency x threads grid at once, as a ``lax.scan`` over
+:func:`make_substep`'s body.  The step is one string of vector ops on every
+backend: each thread-plane read is a one-hot select (:func:`_read_row`),
+each write a one-hot merge (:func:`_write_row`), the histogram update a
+one-hot add, and the idle-skip runs straight-line, so a compiled step holds
+no scatter, no row gather over a thread plane and no ``conditional``.  On
+the TPU, whose thread-slot axis is the vector lanes, a gather's or a
+scatter's rows would be worked through one at a time.
 
 Lexicographic minima
 --------------------
@@ -46,8 +26,8 @@ float's bits: XLA:TPU emulates float64 and cannot rewrite a 64-bit
 (normals below ``2**-126`` flush to zero), so the scheduler also uses no
 sub-normal-magnitude spacing constants.
 
-State layout (the kernel ref contract)
---------------------------------------
+State layout
+------------
 ``G`` cells, ``T`` thread slots, ``P`` prefetch slots, ``S`` SSDs:
 
   ============  ============  =================================================
@@ -87,25 +67,18 @@ global drain horizon (running max of pop times, mirroring the loop's
 shared parked heap -- see the in-step comment) / nothing.  The thread
 index of a slot is its column, so no plane stores thread ids and the
 thread count has no encoding limit.
-
-The K-substep batching contract: one :func:`fused_steps` invocation consumes
-a ``(K, n_u, G)`` block of pre-drawn uniforms and advances the state by
-exactly K substeps -- state crosses the kernel boundary only once per K
-steps, and the uniform feed is the only per-step input, so a scan over
-blocks of K is step-for-step identical to a scan over single steps.
 """
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
-from jax.experimental import pallas as pl
 
 from ..core.sim.arrivals import HIST_BINS, HIST_INV_LN_RATIO, HIST_LO
 from ..core.trace_ir import MEM, PREIO
 
 __all__ = [
     "SPAN_SHIFT", "BIG", "INIT_KEY", "ring_min",
-    "pack_span", "unpack_span", "make_substep", "fused_steps",
+    "pack_span", "unpack_span", "make_substep",
 ]
 
 # Sentinel for "no entry" (parked/inactive threads in the stamp plane).
@@ -151,10 +124,66 @@ def unpack_span(span):
     return span - end * _SPAN, end
 
 
+def _read_row(plane, tid):
+    """``plane[g, tid[g]]`` for a ``(G, T)`` or ``(G, T, K)`` plane, as a
+    one-hot select.
+
+    The select is a row max with every other slot at ``-inf``: it does
+    no arithmetic on any value, so it is exact for every value but NaN
+    (none is stored), ``+inf`` included.  A one-term masked *sum* is
+    not, on XLA:TPU: its emulated float64 is a float32 pair, and an
+    addition that meets the wake plane's ``+inf`` can leave NaN in
+    the low word."""
+    T = plane.shape[1]
+    hot = jax.lax.broadcasted_iota(jnp.int32, (plane.shape[0], T), 1) \
+        == tid[:, None]
+    if plane.ndim == 3:
+        hot = hot[:, :, None]
+    return jnp.max(jnp.where(hot, plane, -jnp.inf), 1)
+
+
+def _write_row(plane, tid, val):
+    """``plane.at[g, tid[g]].set(val[g])`` as a one-hot merge."""
+    T = plane.shape[1]
+    hot = jax.lax.broadcasted_iota(jnp.int32, (plane.shape[0], T), 1) \
+        == tid[:, None]
+    if plane.ndim == 3:
+        return jnp.where(hot[:, :, None], val[:, None, :], plane)
+    return jnp.where(hot, val[:, None], plane)
+
+
+def _grant(submit, clocks, devmask, spacing):
+    """One token-clock grant: ``svc = max(submit, clocks[dev])``, and the
+    selected device's clock advances to ``svc + spacing``.
+
+    ``submit`` is ``(G, 1)``, ``clocks``/``devmask`` are ``(G, n_ssd)``,
+    ``spacing`` broadcasts.  A spacing of 0 disables the clock (grant
+    passes through); a fully-masked row (no IO this step) is not gated:
+    its ``at_dev`` reads 0.0, and simulated time is non-negative, so
+    ``max(submit, 0) == submit``.
+    """
+    enabled = spacing > 0.0
+    at_dev = jnp.sum(jnp.where(devmask, clocks, 0.0), axis=-1, keepdims=True)
+    svc = jnp.where(enabled, jnp.maximum(submit, at_dev), submit)
+    new_clocks = jnp.where(enabled & devmask, svc + spacing, clocks)
+    return svc, new_clocks
+
+
+def _update(submit, devmask, tok, bw, inv_r, cost_bw):
+    """The striped per-device token clocks' grant for one step, IOPS clock
+    first, then bandwidth -- the bandwidth grant sees the IOPS-delayed
+    service time, like ``SSDClocks.submit`` / the compiled loop.
+    ``devmask`` one-hot selects each cell's device (all-zero rows for
+    cells that submit no IO); clocks of unselected devices pass through.
+    All shapes as in :func:`_grant`; returns ``(svc, tok, bw)``."""
+    svc, tok = _grant(submit, tok, devmask, inv_r)
+    svc, bw = _grant(svc, bw, devmask, cost_bw)
+    return svc, tok, bw
+
+
 def make_substep(*, n_u, n_ssd, has_eps, has_rho, has_jitter, has_rio,
                  has_bio, has_bmem, has_lock, has_arr=False, has_lat=False,
-                 has_deadline=False, has_degrade=False, step_form="scatter",
-                 n_cores=1):
+                 has_deadline=False, has_degrade=False, n_cores=1):
     """Build the scheduler substep body, specialized on the static config.
 
     The returned ``substep(state, u, kd, se, arr, nthr_g, n_trace,
@@ -184,14 +213,6 @@ def make_substep(*, n_u, n_ssd, has_eps, has_rho, has_jitter, has_rio,
     IOs submitted at ``now >= T_degrade`` (mid-run device slowdown; same
     submission-time rule as the loops' ``SSDClocks.submit``).
 
-    ``step_form="select"`` switches the per-row thread-plane
-    gathers/scatters to bit-identical one-hot select/merge forms and
-    always runs the starved-cell idle-skip re-derivation instead of
-    branching on whether any cell is starved: straight-line code, the
-    VPU-friendly subset that the Pallas kernel and the TPU's jnp scan run.
-    ``"scatter"`` keeps the gathers, the scatters and the branch.  The
-    resulting values are identical either way.
-
     ``n_cores > 1`` adds a core axis: thread planes become ``(G, C*T)``
     core-major (the column is the global tid), the prefetch window and
     its bandwidth clock become per-core (``pf_slots`` is ``(G, C, P)``,
@@ -201,49 +222,11 @@ def make_substep(*, n_u, n_ssd, has_eps, has_rho, has_jitter, has_rio,
     clocks stay shared -- exactly the generic loop's sharing.  Each step
     first picks the core with the earliest yield clock (the loop's core
     heap; ties break to the lower core id like ``heapq``) and then runs the
-    single-core step body on that core's thread segment.  The
-    ``n_cores == 1`` path is byte-for-byte the pre-existing substep.
+    single-core step body on that core's thread segment.
     """
-    if step_form not in ("scatter", "select"):
-        raise ValueError(f"step_form must be 'scatter' or 'select', "
-                         f"got {step_form!r}")
-    onehot = step_form == "select"
     has_io_clock = has_rio or has_bio
     multicore = n_cores > 1
-    f = jnp.float64
     i4 = jnp.int32
-
-    def sel_thread(plane, tid):
-        """``plane[g, tid[g]]`` -- gather, or a one-hot select.
-
-        The select is a row max with every other slot at ``-inf``: it does
-        no arithmetic on any value, so it is exact for every value but NaN
-        (none is stored), ``+inf`` included.  A one-term masked *sum* is
-        not, on XLA:TPU: its emulated float64 is a float32 pair, and an
-        addition that meets the wake plane's ``+inf`` can leave NaN in
-        the low word."""
-        if onehot:
-            T = plane.shape[1]
-            hot = jax.lax.broadcasted_iota(i4, (plane.shape[0], T), 1) \
-                == tid[:, None]
-            if plane.ndim == 3:
-                hot = hot[:, :, None]
-            return jnp.max(jnp.where(hot, plane, -jnp.inf), 1)
-        if plane.ndim == 3:
-            return jnp.take_along_axis(plane, tid[:, None, None], 1)[:, 0]
-        return jnp.take_along_axis(plane, tid[:, None], 1)[:, 0]
-
-    def upd_thread(plane, tid, val):
-        """``plane.at[g, tid[g]].set(val[g])`` -- scatter or one-hot merge."""
-        if onehot:
-            T = plane.shape[1]
-            hot = jax.lax.broadcasted_iota(i4, (plane.shape[0], T), 1) \
-                == tid[:, None]
-            if plane.ndim == 3:
-                return jnp.where(hot[:, :, None], val[:, None, :], plane)
-            return jnp.where(hot, val[:, None], plane)
-        rows = jnp.arange(plane.shape[0], dtype=i4)
-        return plane.at[rows, tid].set(val)
 
     def substep(s, u, kd, se, arr, nthr_g, n_trace, L_mem_g, warm_g,
                 n_ops, dyn):
@@ -298,8 +281,8 @@ def make_substep(*, n_u, n_ssd, has_eps, has_rho, has_jitter, has_rio,
             # The selected core's ring head / idle-skip, exactly the
             # single-core derivation over its thread segment; the local
             # column plus the core's offset is the global tid.
-            wake_c = sel_thread(wake3, cstar)                # (G, Tpc)
-            stamp_c = sel_thread(stamp3, cstar)
+            wake_c = _read_row(wake3, cstar)                 # (G, Tpc)
+            stamp_c = _read_row(stamp3, cstar)
             ring_wake, ring_stamp = wake_c, stamp_c
         else:
             now = cf[:, 0]
@@ -321,24 +304,13 @@ def make_substep(*, n_u, n_ssd, has_eps, has_rho, has_jitter, has_rio,
         head, slot_tid = ring_head(now)
 
         # -- idle-skip: nothing ready, nothing eligible -> jump to the ------
-        # earliest wake-up and re-derive the keys.  Starvation is rare for
-        # healthy thread counts, so the CPU's jnp scan branches around the
-        # second pass at run time; the kernel and the TPU's scan run it
-        # straight-line.  The values agree either way: a cell that did not
-        # starve re-derives identical keys from an unchanged ``now``.
+        # earliest wake-up and re-derive the keys.  Every step runs the
+        # second pass, straight-line: a cell that did not starve
+        # re-derives identical keys from an unchanged ``now``.
         starved = head >= BIG
-
-        def skip(now_v):
-            w_min = jnp.min(ring_wake, axis=1)
-            now2 = jnp.where(starved, jnp.maximum(now_v, w_min), now_v)
-            return (now2,) + ring_head(now2)
-
-        if onehot:
-            now, head, slot_tid = skip(now)
-        else:
-            now, head, slot_tid = jax.lax.cond(
-                jnp.any(starved), lambda: skip(now),
-                lambda: (now, head, slot_tid))
+        w_min = jnp.min(ring_wake, axis=1)
+        now = jnp.where(starved, jnp.maximum(now, w_min), now)
+        head, slot_tid = ring_head(now)
         if multicore:
             pop_now = now
             tid = cstar * Tpc + slot_tid
@@ -355,7 +327,7 @@ def make_substep(*, n_u, n_ssd, has_eps, has_rho, has_jitter, has_rio,
         # which is why ``ring_min`` breaks that tie toward the woken thread.
         ticket = now
 
-        pft_r = sel_thread(pft, tid)                 # (G, 2) or (G, 3)
+        pft_r = _read_row(pft, tid)                  # (G, 2) or (G, 3)
         pf_tid0 = pft_r[:, 0]
         op_start_r = pft_r[:, 2] if has_lat else None
         i_f, end_f = unpack_span(pft_r[:, 1])
@@ -410,13 +382,9 @@ def make_substep(*, n_u, n_ssd, has_eps, has_rho, has_jitter, has_rio,
                           * HIST_INV_LN_RATIO),
                 0, HIST_BINS - 1).astype(i4)
             inc = jnp.where(rec, 1.0, 0.0)
-            if onehot:
-                hot = jax.lax.broadcasted_iota(
-                    i4, lat_hist.shape, 1) == b[:, None]
-                lat_hist = lat_hist + jnp.where(hot, inc[:, None], 0.0)
-            else:
-                rows = jnp.arange(G, dtype=i4)
-                lat_hist = lat_hist.at[rows, b].add(inc)
+            hot = jax.lax.broadcasted_iota(
+                i4, lat_hist.shape, 1) == b[:, None]
+            lat_hist = lat_hist + jnp.where(hot, inc[:, None], 0.0)
             latmax = jnp.where(rec, jnp.maximum(latmax, sojourn), latmax)
             op_start_new = jnp.where(
                 eoo, arr_next if has_arr else now, op_start_r)
@@ -450,7 +418,6 @@ def make_substep(*, n_u, n_ssd, has_eps, has_rho, has_jitter, has_rio,
                 bw1 = jnp.where(park, svc + cost_bw_io, bw1)
             io_out = (tok1[:, None], bw1[:, None])
         else:
-            from .token_clock import _update
             devmask = (jax.lax.broadcasted_iota(i4, (G, n_ssd), 1)
                        == (io_rr % n_ssd)[:, None]) & park[:, None]
             svc, tok2d, bw2d = _update(
@@ -474,8 +441,8 @@ def make_substep(*, n_u, n_ssd, has_eps, has_rho, has_jitter, has_rio,
         # minimum slot is also the replacement target either way.
         if multicore:
             # The selected core's private window + bandwidth clock.
-            slots_row = sel_thread(pf_slots, cstar)          # (G, P)
-            pf_bw = sel_thread(cores, cstar)[:, 1]
+            slots_row = _read_row(pf_slots, cstar)           # (G, P)
+            pf_bw = _read_row(cores, cstar)[:, 1]
         else:
             slots_row = pf_slots
             pf_bw = cf[:, 1]
@@ -492,10 +459,10 @@ def make_substep(*, n_u, n_ssd, has_eps, has_rho, has_jitter, has_rio,
             pf_bw = jnp.where(issue, pstart + cost_bmem, pf_bw)
         u_pf = u[next(un)] if has_rho else None
         comp = pstart + lmem(u_pf, L_mem_g)
-        slots_row = upd_thread(
+        slots_row = _write_row(
             slots_row, slot,
             jnp.where(issue, comp, slot_min))
-        pf_slots = (upd_thread(pf_slots, cstar, slots_row) if multicore
+        pf_slots = (_write_row(pf_slots, cstar, slots_row) if multicore
                     else slots_row)
         pf_tid = jnp.where(issue, comp, pf_tid0)
 
@@ -515,16 +482,16 @@ def make_substep(*, n_u, n_ssd, has_eps, has_rho, has_jitter, has_rio,
             parked_any = park
             wake_val = jnp.where(park, jnp.maximum(park_until, now),
                                  jnp.inf)
-        stamp = upd_thread(stamp, tid, jnp.where(parked_any, BIG, ticket))
-        wake = upd_thread(wake, tid, wake_val)
+        stamp = _write_row(stamp, tid, jnp.where(parked_any, BIG, ticket))
+        wake = _write_row(wake, tid, wake_val)
         pft_cols = [pf_tid, span_next]
         if has_lat:
             pft_cols.append(op_start_new)
-        pft = upd_thread(pft, tid, jnp.stack(pft_cols, axis=1))
+        pft = _write_row(pft, tid, jnp.stack(pft_cols, axis=1))
 
         crossed = (counted >= n_ops) & ~reached
         if multicore:
-            cores = upd_thread(cores, cstar,
+            cores = _write_row(cores, cstar,
                                jnp.stack([now, pf_bw], axis=1))
             # -- global drain horizon: the loop's cross-core wake-ups -------
             # The scalar loop drains the *shared* parked heap against the
@@ -570,67 +537,3 @@ def make_substep(*, n_u, n_ssd, has_eps, has_rho, has_jitter, has_rio,
         return out
 
     return substep
-
-
-def fused_steps(substep, state, u_block, kd, se, arr, n_trace, L_mem_g,
-                nthr_g, warm_g, n_ops, dyn, *, interpret: bool | None = None):
-    """Advance ``state`` by K substeps in one ``pallas_call`` invocation.
-
-    ``substep`` must come from :func:`make_substep` (built with
-    ``step_form="select"`` for the kernel-friendly op
-    subset); ``u_block`` is the ``(K, n_u, G)`` uniform feed; ``arr`` the
-    shared arrival vector (a 1-wide dummy closed loop) and ``nthr_g`` the
-    per-cell thread counts.  All planes are kernel refs: they are read
-    once, carried through an in-kernel ``fori_loop`` over the K substeps,
-    and written back once, so on a compiled backend the scheduler state
-    never leaves VMEM between substeps.  ``interpret=None`` auto-selects
-    interpreter mode off-TPU, which is how the CPU tests validate
-    bit-identity against the jnp scan path.  The kernel does not compile
-    for TPU yet: its planes and scalars are float64, which Mosaic does
-    not lower (see the module docstring).
-    """
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
-    K = u_block.shape[0]
-    if u_block.shape[1] == 0:
-        # Draw-free configs (no eps/rho/jitter) consume no uniforms; a
-        # zero-size ref breaks pallas_call, so feed a 1-wide dummy block
-        # the substep never reads.
-        u_block = jnp.zeros((K, 1) + u_block.shape[2:], u_block.dtype)
-    n_state = len(state)
-    dyn_arr = jnp.stack([jnp.asarray(d, jnp.float64) for d in dyn])
-
-    def kernel(*refs):
-        ins = refs[:n_state + 9]
-        outs = refs[n_state + 9:]
-        s0 = tuple(r[:] for r in ins[:n_state])
-        (u_ref, kd_ref, se_ref, arr_ref, ntr_ref, lmem_ref, nthr_ref,
-         warm_ref, nops_ref) = ins[n_state:n_state + 9]
-        kd_v, se_v = kd_ref[:], se_ref[:]
-        arr_v = arr_ref[:]
-        n_trace = ntr_ref[0]
-        L_mem_g, warm_g = lmem_ref[:], warm_ref[:]
-        nthr_v = nthr_ref[:]
-        n_ops = nops_ref[0]
-        dyn_v = tuple(nops_ref[1 + j] for j in range(dyn_arr.shape[0]))
-
-        def body(k, s):
-            return substep(s, u_ref[k], kd_v, se_v, arr_v, nthr_v,
-                           n_trace, L_mem_g, warm_g, n_ops, dyn_v)
-
-        final = jax.lax.fori_loop(0, K, body, s0)
-        for ref, val in zip(outs, final):
-            ref[:] = val
-
-    # n_ops and the dynamic scalars travel in one small f64 vector; the
-    # trace length is a (1,) i32 ref.
-    scal = jnp.concatenate([jnp.asarray([n_ops], jnp.float64), dyn_arr])
-    out = pl.pallas_call(
-        kernel,
-        out_shape=tuple(
-            jax.ShapeDtypeStruct(s.shape, s.dtype) for s in state),
-        interpret=interpret,
-    )(*state, u_block, kd, se, arr,
-      jnp.asarray(n_trace, jnp.int32).reshape(1),
-      L_mem_g, nthr_g, warm_g, scal)
-    return out

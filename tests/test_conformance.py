@@ -67,7 +67,7 @@ class TestContractTable:
         # every distinct execution path pairs off against a reference
         flat = " ".join(part for c in CONTRACTS.values() for part in c.pair)
         for backend in ("simulate", "simulate_compiled", "sweep_grid",
-                        "use_pallas", "sweep_cluster"):
+                        "sweep_cluster"):
             assert backend in flat
 
     def test_jax_grid_tol_is_base_at_and_above_ref(self):
@@ -223,7 +223,7 @@ class TestCorpus:
 
     def test_cheapest_corpus_entry_replays_green(self):
         # tier-1 smoke: the smallest single-host spec through the full
-        # differential pass (compiled + jax + pallas)
+        # differential pass (compiled + jax)
         scs = [(p, Scenario.from_json(p.read_text()))
                for p in _corpus_paths()]
         path, sc = min(

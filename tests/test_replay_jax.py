@@ -6,8 +6,10 @@ Guarantees, strongest first:
      decoded trace round-trips exactly, for every registered engine's
      default-pairing trace and for arbitrary (hypothesis-generated) op
      lists.
-  2. The Pallas token-clock kernel (interpreter mode on CPU) is
-     *bit-identical* to the pure-jnp path inside the grid.
+  2. The step's pieces are exact: the one-hot row read returns each
+     slot's value bit for bit whatever the other slots hold, the row
+     write changes one slot, the token-clock grant gates as the loops do,
+     and every partition of the grid into cohorts gives the same bits.
   3. Per-cell throughput is *tolerance-equivalent* to the loop backends:
      the jax grid reproduces the loops' scheduling and device arithmetic
      but draws from a different RNG stream (threefry vs. Mersenne), so
@@ -36,6 +38,7 @@ from repro.core.sim import SimConfig, simulate_compiled, sweep_latency
 from repro.core.sim import replay_jax, sweep as sweep_mod
 from repro.core.sim.replay_jax import TraceArrays, lower_trace, sweep_grid
 from repro.core.trace_ir import CPU, MEM, POSTIO, PREIO, CompiledTrace, Op
+from repro.kernels import sched_step as sk
 
 from _hypothesis_support import given, settings, st  # optional-hypothesis shim
 
@@ -106,82 +109,102 @@ class TestLowering:
         assert np.array_equal(back.bounds, trace.bounds)
 
 
-# -- 2. the Pallas kernel ----------------------------------------------------
+# -- 2. the step's pieces -----------------------------------------------------
 
 
-class TestPallasTokenClock:
-    def test_interpreter_kernel_matches_jnp_path_exactly(self, lsm_small):
-        # Same draws, same arithmetic -> the whole grid result must be
-        # numerically identical, not just close.  Tiny cell: interpreter
-        # mode runs the kernel body per scheduler step.
-        cfg = SimConfig(P=12, seed=7, n_ssd=2, R_io=250e3,
-                        L_switch=0.3 * US)
-        ref = sweep_grid(cfg, lsm_small.trace, [5 * US], [8], n_ops=150)
-        pal = sweep_grid(cfg, lsm_small.trace, [5 * US], [8], n_ops=150,
-                         use_pallas=True)
-        assert np.array_equal(ref.throughput, pal.throughput)
-        assert np.array_equal(ref.mem_stall_total, pal.mem_stall_total)
-
-    @pytest.mark.parametrize("engine", ENGINES)
-    def test_fused_step_bit_identical_per_engine(self, engine):
-        # Every registered engine produces a different suboperation mix
-        # (MEM chains, PREIO bursts, lock sections); the fused whole-step
-        # kernel must replay each of them bit-for-bit like the jnp scan,
-        # not just within tolerance.
-        sc = default_scenario(engine, n_keys=2_000, n_wl_ops=600)
-        store = available_engines()[engine](sc.n_keys, **sc.engine_kwargs)
-        wname, wkw = sc.resolved_workload()
-        wl = workloads.create_workload(wname, sc.n_keys, sc.n_wl_ops, **wkw)
-        trace = run_trace(store, wl).trace
-        cfg = sc.sim_config()
-        ref = sweep_grid(cfg, trace, [1 * US, 5 * US], [4, 8], n_ops=120)
-        pal = sweep_grid(cfg, trace, [1 * US, 5 * US], [4, 8], n_ops=120,
-                         use_pallas=True, substeps=4)
-        for fld in ("throughput", "time", "mem_stall_total",
-                    "mem_accesses"):
-            assert np.array_equal(getattr(ref, fld), getattr(pal, fld)), fld
-
+class TestStepPieces:
     def test_no_jitter_deterministic_exact_match(self, lsm_small):
         # Every stochastic device feature off -> zero uniforms consumed
-        # per step (the n_u=0 edge of the kernel's uniform-feed contract);
-        # the replay is then a deterministic function of the trace, and
-        # both paths must agree exactly with themselves across calls and
-        # with each other.
+        # per step (the n_u=0 edge of the scan's uniform feed); the replay
+        # is then a deterministic function of the trace, and must agree
+        # exactly with itself across calls.
         cfg = SimConfig(P=12, seed=7, L_io_jitter=0.0)
         assert cfg.eps == 0.0 and cfg.rho == 1.0
         ref = sweep_grid(cfg, lsm_small.trace, [1 * US, 8 * US], [8, 16],
                          n_ops=200)
         again = sweep_grid(cfg, lsm_small.trace, [1 * US, 8 * US], [8, 16],
                            n_ops=200)
-        pal = sweep_grid(cfg, lsm_small.trace, [1 * US, 8 * US], [8, 16],
-                         n_ops=200, use_pallas=True)
-        assert np.array_equal(ref.throughput, again.throughput)
-        assert np.array_equal(ref.throughput, pal.throughput)
-        assert np.array_equal(ref.time, pal.time)
-        assert np.array_equal(ref.mem_stall_total, pal.mem_stall_total)
-        assert np.array_equal(ref.mem_accesses, pal.mem_accesses)
+        for fld in ("throughput", "time", "mem_stall_total",
+                    "mem_accesses"):
+            assert np.array_equal(getattr(ref, fld), getattr(again, fld)), fld
 
     def test_kernel_unit_grant_semantics(self):
-        from repro.kernels.token_clock import (
-            token_clock_update,
-            token_clock_update_ref,
-        )
-
-        submit = np.array([10.0, 20.0, 30.0])
+        submit = np.array([[10.0], [20.0], [30.0]])
         devmask = np.array([[True, False], [False, True], [False, False]])
         tok = np.array([[12.0, 0.0], [0.0, 19.0], [99.0, 99.0]])
         bw = np.zeros((3, 2))
-        for fn in (token_clock_update_ref, token_clock_update):
-            svc, tok2, bw2 = fn(jax.numpy.asarray(submit),
-                                jax.numpy.asarray(devmask),
-                                jax.numpy.asarray(tok),
-                                jax.numpy.asarray(bw), 0.5, 0.0)
-            svc, tok2, bw2 = map(np.asarray, (svc, tok2, bw2))
-            assert svc[0] == 12.0 and tok2[0, 0] == 12.5   # gated by clock
-            assert svc[1] == 20.0 and tok2[1, 1] == 20.5   # clock behind
-            assert svc[2] == 30.0                          # masked row:
-            assert np.all(tok2[2] == 99.0)                 # clocks untouched
-            assert np.all(bw2 == 0.0)                      # disabled limit
+        jnp = jax.numpy
+        with jax.enable_x64(True):
+            svc, tok2, bw2 = sk._update(
+                jnp.asarray(submit), jnp.asarray(devmask), jnp.asarray(tok),
+                jnp.asarray(bw), jnp.float64(0.5), jnp.float64(0.0))
+        svc, tok2, bw2 = np.asarray(svc)[:, 0], np.asarray(tok2), \
+            np.asarray(bw2)
+        assert svc[0] == 12.0 and tok2[0, 0] == 12.5   # gated by clock
+        assert svc[1] == 20.0 and tok2[1, 1] == 20.5   # clock behind
+        assert svc[2] == 30.0                          # masked row:
+        assert np.all(tok2[2] == 99.0)                 # clocks untouched
+        assert np.all(tok2[0, 1:] == 0.0) and tok2[1, 0] == 0.0
+        assert np.all(bw2 == 0.0)                      # disabled limit
+
+    # What the slots around the read one hold: the wake plane's +inf, the
+    # stamp plane's BIG and INIT_KEY sentinels, or all of them and times.
+    ROW_FILLERS = {"inf": [np.inf], "big": [sk.BIG],
+                   "init_key": [sk.INIT_KEY],
+                   "mix": [np.inf, sk.BIG, sk.INIT_KEY, 3.25e-6, 0.0]}
+    # What the read slot holds, one value a row: every sentinel, zero,
+    # times with all 52 mantissa bits in use, and integers near 2**52.
+    ROW_VALUES = [np.inf, sk.BIG, sk.INIT_KEY, 0.0, 1e-7 / 3, 0.1,
+                  2.0 ** 52 - 1.0, 123456.789012345, 7.5e-5]
+
+    @staticmethod
+    def _rows(ndim, where, fill):
+        G, T, K = len(TestStepPieces.ROW_VALUES), 7, 3
+        tid = np.full(G, 0 if where == "first" else T - 1, np.int32)
+        shape = (G, T) if ndim == 2 else (G, T, K)
+        plane = np.resize(np.asarray(fill, np.float64),
+                          int(np.prod(shape))).reshape(shape)
+        vals = np.asarray(TestStepPieces.ROW_VALUES)
+        if ndim == 3:
+            vals = np.stack([vals, -vals, vals / 7.0], axis=1)
+        plane[np.arange(G), tid] = vals
+        return plane, tid, vals
+
+    @pytest.mark.parametrize("where", ["first", "last"])
+    @pytest.mark.parametrize("ndim", [2, 3])
+    @pytest.mark.parametrize("filler", sorted(ROW_FILLERS))
+    def test_read_row_exact(self, filler, ndim, where):
+        """The one-hot read is a row max over ``-inf`` elsewhere: it
+        returns the slot's value bit for bit, ``+inf`` and the
+        sentinels included, whatever the other slots hold."""
+        plane, tid, vals = self._rows(ndim, where,
+                                      self.ROW_FILLERS[filler])
+        with jax.enable_x64(True):
+            got = np.asarray(sk._read_row(jax.numpy.asarray(plane),
+                                          jax.numpy.asarray(tid)))
+        assert got.dtype == np.float64
+        assert np.array_equal(got, vals)
+        assert np.array_equal(got.view(np.int64), vals.view(np.int64))
+
+    @pytest.mark.parametrize("where", ["first", "last"])
+    @pytest.mark.parametrize("ndim", [2, 3])
+    def test_write_row_changes_one_slot(self, ndim, where):
+        plane, tid, _ = self._rows(ndim, where,
+                                   self.ROW_FILLERS["mix"])
+        new = np.arange(1, plane.shape[0] + 1, dtype=np.float64) / 3.0
+        if ndim == 3:
+            new = np.stack([new, -new, new * 5.0], axis=1)
+        want = plane.copy()
+        want[np.arange(plane.shape[0]), tid] = new
+        with jax.enable_x64(True):
+            got = np.asarray(sk._write_row(jax.numpy.asarray(plane),
+                                           jax.numpy.asarray(tid),
+                                           jax.numpy.asarray(new)))
+        assert np.array_equal(got, want)
+        changed = (got != plane).reshape(plane.shape[0], plane.shape[1],
+                                         -1).any(axis=2)
+        assert np.array_equal(changed.sum(axis=1),
+                              np.ones(plane.shape[0], np.int64))
 
 
 # -- 3. tolerance equivalence against the loop backends ----------------------
@@ -296,16 +319,6 @@ class TestGridEquivalence:
                                  [1 * US, 5 * US], [8, 16], n_ops=6000)
         assert worst < jax_grid_tol(6000, slack=1.1), f"{kw}: {worst:.2%}"
 
-    def test_multicore_matches_pallas_path(self, lsm_small):
-        cfg = SimConfig(P=12, seed=7, n_cores=2)
-        ref = sweep_grid(cfg, lsm_small.trace, [1 * US, 5 * US], [4, 8],
-                         n_ops=300)
-        pal = sweep_grid(cfg, lsm_small.trace, [1 * US, 5 * US], [4, 8],
-                         n_ops=300, use_pallas=True, substeps=4)
-        for fld in ("throughput", "time", "mem_stall_total",
-                    "mem_accesses"):
-            assert np.array_equal(getattr(ref, fld), getattr(pal, fld)), fld
-
 
 class TestRingOrder:
     """The derived ring pops threads in the loops' FIFO order.
@@ -344,8 +357,7 @@ class TestRingOrder:
 
 class TestBackendGuards:
     """Off the CPU backend, the grid refuses what would silently leave the
-    accelerator (host-device sharding) or cannot compile there (the
-    float64 Pallas kernel) -- before anything is traced."""
+    accelerator (host-device sharding) -- before anything is traced."""
 
     @pytest.fixture
     def on_tpu(self, monkeypatch):
@@ -357,24 +369,19 @@ class TestBackendGuards:
             sweep_grid(SimConfig(), lsm_small.trace, [1 * US], [8],
                        host_devices=2)
 
-    def test_pallas_raises_off_cpu(self, lsm_small, on_tpu):
-        with pytest.raises(ValueError, match="Queue 1 item 3"):
-            sweep_grid(SimConfig(), lsm_small.trace, [1 * US], [8],
-                       use_pallas=True)
-
 
 # -- 3b. cohorts, early exit, host sharding ----------------------------------
 
 
-def _het_grids(trace, cfg, n_ops=300, **kw):
+def _het_grids(trace, cfg, n_ops=300):
     """The same heterogeneous cells through the cohort early-exit layout
     and the monolithic single-scan layout (PR 6's shape: every cell padded
     to T_max, scanned to the one global bound)."""
     lats = [0.5 * US, 5 * US]
     cands = [4, 8, 24]            # three pow2 buckets, uneven warmups
-    coh = sweep_grid(cfg, trace, lats, cands, n_ops=n_ops, **kw)
+    coh = sweep_grid(cfg, trace, lats, cands, n_ops=n_ops)
     mono = sweep_grid(cfg, trace, lats, cands, n_ops=n_ops,
-                      bucket_threads=False, early_exit=False, **kw)
+                      bucket_threads=False, early_exit=False)
     return coh, mono
 
 
@@ -395,11 +402,6 @@ class TestCohortEarlyExit:
                     "mem_accesses"):
             assert np.array_equal(getattr(coh, fld),
                                   getattr(mono, fld)), (engine, fld)
-
-    def test_cohorts_bit_identical_under_pallas(self, lsm_small):
-        coh, mono = _het_grids(lsm_small.trace, SimConfig(P=12, seed=7),
-                               use_pallas=True, substeps=4)
-        assert np.array_equal(coh.throughput, mono.throughput)
 
     def test_cohorts_bit_identical_with_devices_and_cores(self, lsm_small):
         # skew from the device axis too: multi-SSD token clocks and a
@@ -490,9 +492,6 @@ class TestCohortEarlyExit:
         with pytest.raises(ValueError, match="host_devices"):
             sweep_grid(SimConfig(), lsm_small.trace, [1 * US], [8],
                        host_devices=0)
-        with pytest.raises(ValueError, match="Pallas"):
-            sweep_grid(SimConfig(), lsm_small.trace, [1 * US], [8],
-                       host_devices=2, use_pallas=True)
         import jax as _jax
         avail = len(_jax.devices("cpu"))
         with pytest.raises(ValueError, match="host CPU"):
@@ -962,10 +961,10 @@ class TestOpenLoopGrid:
     CANDS = [8, 16]
     N_OPS = 400
 
-    def _grid(self, cfg, trace, arr, **kw):
+    def _grid(self, cfg, trace, arr):
         return sweep_grid(cfg, trace, self.LATS, self.CANDS,
                           n_ops=self.N_OPS, arrivals=arr,
-                          collect_percentiles=True, **kw)
+                          collect_percentiles=True)
 
     @pytest.mark.parametrize("mode", sorted(ARR_SPECS))
     def test_grid_tail_close_to_compiled_loop(self, hash_small, mode):
@@ -1012,19 +1011,6 @@ class TestOpenLoopGrid:
         # the gap (P90/P99 agree to ~3% there).  Gate on the spread.
         if rs.p90 < 1.5 * rs.p50:
             assert gs.p50 == pytest.approx(rs.p50, rel=P50_TOL)
-
-    def test_pallas_open_loop_bit_identical(self, hash_small):
-        cfg = SimConfig(P=12, seed=7)
-        spec = dataclasses.replace(ARR_SPECS["bursty"], deadline=300e-6)
-        arr = _arrival_array(spec, cfg, self.CANDS, self.N_OPS)
-        ref = self._grid(cfg, hash_small.trace, arr,
-                         deadline=spec.deadline)
-        pal = self._grid(cfg, hash_small.trace, arr,
-                         deadline=spec.deadline, use_pallas=True)
-        for f in ("throughput", "p50", "p90", "p99", "lat_max",
-                  "lat_count", "missed"):
-            assert np.array_equal(getattr(ref, f), getattr(pal, f),
-                                  equal_nan=True), f
 
     def test_closed_loop_percentiles_leave_throughput_identical(
             self, hash_small):
@@ -1091,9 +1077,9 @@ class TestOpenLoopGrid:
                        deadline=-1.0)
 
 
-# -- 5. the scan step's op form ----------------------------------------------
+# -- 5. one step, any partition ----------------------------------------------
 
-STEP_FORM_CASES = {
+PARTITION_CASES = {
     **{engine: dict(engine=engine) for engine in ENGINES},
     "open_loop_deadline_pct": dict(engine="hash-index", open_loop=True),
     "ssd2_token_clocks": dict(engine="lsm", cfg=dict(
@@ -1104,18 +1090,20 @@ STEP_FORM_CASES = {
     "cores4_inf_wake": dict(engine="lsm", cands=[1, 3], cfg=dict(
         n_cores=4, L_io=40 * US)),
 }
-STEP_FORM_FIELDS = ("throughput", "time", "mem_stall_total", "mem_accesses",
+PARTITION_FIELDS = ("throughput", "time", "mem_stall_total", "mem_accesses",
                     "p50", "p90", "p99", "lat_max", "lat_count", "missed",
                     "lat_hist")
 
 
-@pytest.mark.parametrize("case", sorted(STEP_FORM_CASES))
+@pytest.mark.parametrize("case", sorted(PARTITION_CASES))
 def test_select_step_bit_identical_to_scatter_step(case, monkeypatch):
-    """The lane-tiled step (one-hot selects and merges over the thread
-    planes, the idle-skip straight-line), forced on the CPU, gives every
-    number of every cell the CPU's step (gathers, scatters, a branch
-    around the idle-skip) gives, bit for bit."""
-    spec = STEP_FORM_CASES[case]
+    """The TPU's partition (one cohort per 128-lane tile), forced on the
+    CPU, gives every number of every cell the CPU's power-of-two
+    partition (one cohort per thread bucket) gives, bit for bit: a cell's
+    numbers do not depend on the plane width or the cells it shares a
+    scan with.  The name is from when the CPU ran a gather/scatter form
+    of the step; both sides now run the one select form."""
+    spec = PARTITION_CASES[case]
     sc = default_scenario(spec["engine"], n_keys=2_000, n_wl_ops=600)
     store = available_engines()[spec["engine"]](sc.n_keys,
                                                 **sc.engine_kwargs)
@@ -1124,6 +1112,7 @@ def test_select_step_bit_identical_to_scatter_step(case, monkeypatch):
         wname, sc.n_keys, sc.n_wl_ops, **wkw)).trace
     cfg = dataclasses.replace(sc.sim_config(), **spec.get("cfg", {}))
     cands, n_ops = spec.get("cands", [4, 8]), 150
+    lats = [1 * US, 5 * US]
     kw = {}
     if spec.get("open_loop"):
         arr_spec = ArrivalSpec(kind="poisson", rate=40e3, seed=5,
@@ -1132,18 +1121,22 @@ def test_select_step_bit_identical_to_scatter_step(case, monkeypatch):
                   collect_percentiles=True, deadline=arr_spec.deadline)
 
     def grid():
-        return sweep_grid(cfg, trace, [1 * US, 5 * US], cands, n_ops=n_ops,
-                          **kw)
+        return sweep_grid(cfg, trace, lats, cands, n_ops=n_ops, **kw)
 
-    scatter = grid()
+    pow2 = grid()
     monkeypatch.setattr(replay_jax, "_lane_tiled", lambda: True)
-    select = grid()
-    assert scatter.record.step_form == "scatter"
-    assert select.record.step_form == "select"
-    for fld in STEP_FORM_FIELDS:
-        a, b = getattr(scatter, fld), getattr(select, fld)
+    lane = grid()
+    n_lat, C = len(lats), cfg.n_cores
+    assert [(c.cells, c.T_max, c.thread_slots)
+            for c in pow2.record.cohorts] == [
+        (n_lat, n, n_lat * C * n) for n in cands]
+    assert [(c.cells, c.T_max, c.thread_slots)
+            for c in lane.record.cohorts] == [
+        (n_lat * len(cands), max(cands), n_lat * C * sum(cands))]
+    for fld in PARTITION_FIELDS:
+        a, b = getattr(pow2, fld), getattr(lane, fld)
         assert (a is None) == (b is None), fld
         if a is not None:
             assert np.array_equal(a, b, equal_nan=True), fld
     if spec.get("open_loop"):      # both sides of the deadline are hit
-        assert scatter.lat_count.sum() > 0 and scatter.missed.sum() > 0
+        assert pow2.lat_count.sum() > 0 and pow2.missed.sum() > 0

@@ -10,8 +10,9 @@ The grid is partitioned and its step built as on the chip
 (``replay_jax._lane_tiled`` forced on).  Every case also asserts that the
 lowered program holds no 64-bit ``bitcast_convert`` (XLA:TPU emulates
 float64 and cannot rewrite one), and that the compiled scan step -- the
-inner ``while`` body -- runs in its select form: no ``scatter`` in it or
-in any computation it calls, and no ``conditional``.
+inner ``while`` body -- holds no ``scatter`` in it or in any computation
+it calls, and no ``conditional``.  The CPU twin of that census compiles
+each benchmark cell's grid for the CPU, partitioned as on the CPU.
 
 The topology is described inside a fixture, never at import time, so every
 pytest-xdist worker collects the same tests and only the worker running
@@ -20,6 +21,7 @@ this file loads the TPU compiler.
 import dataclasses
 import importlib.util
 import re
+import sys
 from pathlib import Path
 
 import numpy as np
@@ -29,24 +31,30 @@ jax = pytest.importorskip("jax")
 
 from jax.sharding import SingleDeviceSharding  # noqa: E402
 
-from repro.core.experiment import Experiment, RunOptions  # noqa: E402
+from repro.core.experiment import (  # noqa: E402
+    Experiment,
+    RunOptions,
+    Scenario,
+)
 from repro.core.sim import replay_jax  # noqa: E402
 from repro.core.sim.arrivals import HIST_BINS  # noqa: E402
 
 ROOT = Path(__file__).resolve().parents[1]
 
 
-def _chip_smoke():
-    spec = importlib.util.spec_from_file_location(
-        "chip_smoke", ROOT / "chip_smoke.py")
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
+def _load(name, path):
+    """The module at ``ROOT / path``, imported once as ``name``."""
+    if name not in sys.modules:
+        spec = importlib.util.spec_from_file_location(name, ROOT / path)
+        sys.modules[name] = importlib.util.module_from_spec(spec)
+        spec.loader.exec_module(sys.modules[name])
+    return sys.modules[name]
 
 
 def _phase(label):
     """The chip_smoke scenario with this label, full size."""
-    for _, name, sc in _chip_smoke()._scenarios(tiny=False):
+    for _, name, sc in _load("chip_smoke", "chip_smoke.py")._scenarios(
+            tiny=False):
         if name == label:
             return sc
     raise KeyError(label)
@@ -95,10 +103,21 @@ def no_persistent_cache():
         cc.reset_cache()
 
 
-def _record_grid_calls(monkeypatch, sc):
-    """Run ``sc`` on the jax backend, partitioned and with its step built
-    as on the chip, with the grid program replaced by a recorder; returns
-    ``[(args, static), ...]``, one per cohort."""
+def _bench_cell(name):
+    """The scenario one sweep of the benchmark cell ``name`` runs."""
+    cell = _load("bench_cells", "bench/harness/cells.py").load_cell(name)
+    return Scenario.from_dict(cell.scenario_dict(7, 11))
+
+
+BENCH_CELLS = ("lsm_zipf099.paper_grid", "hash_uniform_2ssd.paper_grid",
+               "lsm_zipf099.wide_grid", "lsm_zipf099.open_loop",
+               "tree_uniform.paper_grid")
+
+
+def _record_grid_calls(monkeypatch, sc, lane_tiled=True):
+    """Run ``sc`` on the jax backend with the grid program replaced by a
+    recorder, partitioned as on the chip (``lane_tiled``) or as on the
+    CPU; returns ``[(args, static), ...]``, one per cohort."""
     calls = []
 
     def record(*args, **static):
@@ -117,7 +136,8 @@ def _record_grid_calls(monkeypatch, sc):
         return out
 
     monkeypatch.setattr(replay_jax, "_run_grid", record)
-    monkeypatch.setattr(replay_jax, "_lane_tiled", lambda: True)
+    if lane_tiled:
+        monkeypatch.setattr(replay_jax, "_lane_tiled", lambda: True)
     Experiment(sc, RunOptions(backend="jax",
                               collect_percentiles=bool(sc.arrival))).run()
     return calls
@@ -175,7 +195,6 @@ def test_grid_compiles_for_v5e(case, one_chip, no_persistent_cache,
     calls = _record_grid_calls(monkeypatch, CASES[case]())
     assert calls, "the jax backend never reached the grid program"
     args, static = max(calls, key=lambda c: c[1]["T_max"])
-    assert static["step_form"] == "select"
     shapes = jax.tree_util.tree_map(
         lambda a: jax.ShapeDtypeStruct(np.shape(a), a.dtype,
                                        sharding=one_chip), args)
@@ -188,4 +207,24 @@ def test_grid_compiles_for_v5e(case, one_chip, no_persistent_cache,
     body, reached = _scan_step_opcodes(compiled.as_text())
     assert "fusion" in body, "the inner while body was not found whole"
     assert "conditional" not in body, "a run-time branch in the scan step"
+    assert "scatter" not in reached, "a scatter in the scan step"
+
+
+@pytest.mark.parametrize("case", [*BENCH_CELLS, "cores4"])
+def test_grid_step_census_on_cpu(case, monkeypatch):
+    """The CPU runs the chip's step: each benchmark cell's widest cohort
+    (and the four-core phase's), partitioned as on the CPU and compiled
+    for it, has no ``scatter`` and no ``conditional`` in its scan step."""
+    run_grid = replay_jax._run_grid
+    sc = CASES[case]() if case in CASES else _bench_cell(case)
+    calls = _record_grid_calls(monkeypatch, sc, lane_tiled=False)
+    assert calls, "the jax backend never reached the grid program"
+    args, static = max(calls, key=lambda c: c[1]["T_max"])
+    shapes = jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(np.shape(a), a.dtype), args)
+    with jax.enable_x64(True), jax.threefry_partitionable(False):
+        compiled = run_grid.lower(*shapes, **static).compile()
+    body, reached = _scan_step_opcodes(compiled.as_text())
+    assert "fusion" in body, "the inner while body was not found whole"
+    assert "conditional" not in reached, "a run-time branch in the scan step"
     assert "scatter" not in reached, "a scatter in the scan step"
