@@ -38,43 +38,53 @@ class TreeIndexStore:
         self.flush_every = max(flush_block // value_size, 1)
         rng = np.random.default_rng(seed)
         order = rng.permutation(n_keys)
-        # array-based BST per sprig: node i has key keys[i], children l/r
         self.sprig_of = (
             (np.arange(n_keys, dtype=np.uint64) * np.uint64(0x9E3779B97F4A7C15))
             % np.uint64(n_sprigs)
         ).astype(np.int64)
-        self.root = [-1] * n_sprigs
-        self.key = np.empty(n_keys, dtype=np.int64)
-        self.left = np.full(n_keys, -1, dtype=np.int64)
-        self.right = np.full(n_keys, -1, dtype=np.int64)
-        self._n_nodes = 0
-        for k in order.tolist():
-            self._insert(int(k))
+        # array-based BST per sprig: node i holds the i-th inserted key
+        # order[i], children left/right (-1: none)
+        self.key = order.astype(np.int64)
+        self.left, self.right, self.root = self._bulk_load(
+            self.key, self.sprig_of[self.key], n_sprigs
+        )
         self._pending_writes = 0
 
-    def _insert(self, k: int) -> int:
-        """Untraced build-time insert; returns hop count."""
-        i = self._n_nodes
-        self.key[i] = k
-        self._n_nodes += 1
-        s = int(self.sprig_of[k])
-        cur = self.root[s]
-        hops = 0
-        if cur < 0:
-            self.root[s] = i
-            return 0
-        while True:
-            hops += 1
-            if k < self.key[cur]:
-                if self.left[cur] < 0:
-                    self.left[cur] = i
-                    return hops
-                cur = self.left[cur]
-            else:
-                if self.right[cur] < 0:
-                    self.right[cur] = i
-                    return hops
-                cur = self.right[cur]
+    @staticmethod
+    def _bulk_load(key: np.ndarray, sprig: np.ndarray, n_sprigs: int):
+        """Children and roots of the BSTs that inserting ``key`` in order gives.
+
+        Such a tree is the Cartesian tree of its keys with the insertion
+        index as min-heap priority: a node's parent is the later inserted of
+        its nearest earlier-inserted neighbours in key order within its
+        sprig, and it is the right child of a lower parent, the left child
+        of a higher one.  Both neighbours come from a nearest-smaller-value
+        search by pointer jumping; nodes are numbered by insertion index.
+        """
+        n = len(key)
+        node = np.lexsort((key, sprig))  # nodes in (sprig, key) order
+        same = sprig[node[1:]] == sprig[node[:-1]]
+        pos = np.arange(n)
+        below = np.where(np.r_[False, same], pos - 1, -1)
+        above = np.where(np.r_[same, False], pos + 1, -1)
+        for ptr in (below, above):
+            # until ptr[j] is -1 or was inserted before node[j], jump over it
+            live = pos[ptr >= 0]
+            while len(live):
+                live = live[node[ptr[live]] > node[live]]
+                ptr[live] = ptr[ptr[live]]
+                live = live[ptr[live] >= 0]
+        # the nearest earlier-inserted node below / above (-1: none)
+        lo = np.where(below >= 0, node[below], -1)
+        hi = np.where(above >= 0, node[above], -1)
+        left = np.full(n, -1, dtype=np.int64)
+        right = np.full(n, -1, dtype=np.int64)
+        right[lo[lo > hi]] = node[lo > hi]  # parent below: the later inserted
+        left[hi[hi > lo]] = node[hi > lo]
+        root = np.full(n_sprigs, -1, dtype=np.int64)
+        is_root = lo == hi  # both -1
+        root[sprig[node[is_root]]] = node[is_root]
+        return left, right, root.tolist()
 
     def _sprig(self, k: int) -> int:
         # python ints: intentional 64-bit multiplicative hash without

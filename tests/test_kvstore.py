@@ -50,6 +50,83 @@ class TestTreeIndexStore:
         assert tr.io_per_op == pytest.approx(1.0, abs=0.01)
 
 
+def _sequential_build(n_keys: int, n_sprigs: int, seed: int):
+    """Plain reference: insert the keys one at a time, as the engine's draws
+    order them, into per-sprig BSTs (nodes numbered by insertion order)."""
+    order = np.random.default_rng(seed).permutation(n_keys)
+    sprig_of = (
+        (np.arange(n_keys, dtype=np.uint64) * np.uint64(0x9E3779B97F4A7C15))
+        % np.uint64(n_sprigs)
+    ).astype(np.int64)
+    key = np.empty(n_keys, dtype=np.int64)
+    left = np.full(n_keys, -1, dtype=np.int64)
+    right = np.full(n_keys, -1, dtype=np.int64)
+    root = [-1] * n_sprigs
+    for i, k in enumerate(order.tolist()):
+        key[i] = k
+        s = int(sprig_of[k])
+        cur = root[s]
+        if cur < 0:
+            root[s] = i
+            continue
+        while True:
+            side = left if k < key[cur] else right
+            if side[cur] < 0:
+                side[cur] = i
+                break
+            cur = side[cur]
+    return key, left, right, root
+
+
+class TestTreeBulkLoad:
+    """The numpy bulk load builds the trees that one insert per key builds."""
+
+    @pytest.mark.parametrize("seed", [0, 1, 12345])
+    @pytest.mark.parametrize(
+        "n_keys,n_sprigs",
+        [(1, 256), (7, 1), (1000, 256), (300, 4096), (100_000, 256)],
+    )
+    def test_equals_sequential_insert(self, n_keys, n_sprigs, seed):
+        store = TreeIndexStore(n_keys, n_sprigs=n_sprigs, seed=seed)
+        key, left, right, root = _sequential_build(n_keys, n_sprigs, seed)
+        np.testing.assert_array_equal(store.key, key)
+        np.testing.assert_array_equal(store.left, left)
+        np.testing.assert_array_equal(store.right, right)
+        assert store.root == root
+        assert all(type(r) is int for r in store.root)
+        # every node but the roots has exactly one parent
+        children = np.concatenate([store.left, store.right])
+        n_parents = np.bincount(children[children >= 0], minlength=n_keys)
+        is_root = np.zeros(n_keys, dtype=bool)
+        is_root[[r for r in store.root if r >= 0]] = True
+        np.testing.assert_array_equal(n_parents, np.where(is_root, 0, 1))
+        # an in-order walk of each sprig yields its keys, sorted
+        for s, r in enumerate(store.root):
+            walked, stack, cur = [], [], r
+            while stack or cur >= 0:
+                while cur >= 0:
+                    stack.append(cur)
+                    cur = int(store.left[cur])
+                cur = stack.pop()
+                walked.append(int(store.key[cur]))
+                cur = int(store.right[cur])
+            np.testing.assert_array_equal(
+                walked, np.flatnonzero(store.sprig_of == s)
+            )
+
+    @pytest.mark.parametrize("read_write", [(1, 0), (1, 1)])
+    def test_trace_equals_sequential_insert(self, read_write):
+        n_keys, seed = 10_000, 1
+        wl = workloads.uniform(n_keys, 3000, read_write, seed=2)
+        bulk = run_trace(TreeIndexStore(n_keys, seed=seed), wl).trace
+        ref_store = TreeIndexStore(n_keys, seed=seed)
+        (ref_store.key, ref_store.left, ref_store.right,
+         ref_store.root) = _sequential_build(n_keys, ref_store.n_sprigs, seed)
+        ref = run_trace(ref_store, wl).trace
+        for col in ("kinds", "durs", "bounds"):
+            np.testing.assert_array_equal(getattr(bulk, col), getattr(ref, col))
+
+
 class TestLSMStore:
     def test_zipf_hit_ratio(self):
         store = LSMStore(NK)
